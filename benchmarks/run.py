@@ -11,8 +11,8 @@ remaining benchmarks silently skipped — is the bug this replaces).
 point is the *trajectory* of the numbers, not their absolute scale).
 
 Figure scripts that need many host devices (fig4 weak scaling; the dry-run
-itself) run as subprocesses so this process keeps the default single
-device.
+itself) run as subprocesses on the CPU backend, so this process keeps the
+default single device and the only claim on an accelerator.
 """
 from __future__ import annotations
 
@@ -34,6 +34,9 @@ for _p in (_ROOT, os.path.join(_ROOT, "src")):
 def _subprocess_rows(module: str, timeout: int = 1800) -> tuple[list, str]:
     env = dict(os.environ)
     env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
+    # the child works on forced host devices; this process has touched JAX
+    # and holds the accelerator, which a second process must never claim
+    env["JAX_PLATFORMS"] = "cpu"
     t0 = time.perf_counter()
     r = subprocess.run([sys.executable, "-m", module], env=env,
                        capture_output=True, text=True, timeout=timeout)

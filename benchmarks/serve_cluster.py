@@ -12,8 +12,9 @@ the functional gates run.
 
 Always asserted, gated or not:
 
-* cluster outputs are bit-exact with the unbatched single-engine
-  reference, regardless of which replica served each request;
+* cluster outputs are bit-exact with the single engine's
+  ``generate_reference`` (one request per call, at the serving width),
+  regardless of which replica served each request;
 * zero post-warmup recompiles on every replica AND on the single engine
   (long prompts ride chunked paged prefill, not cold exact compiles);
 * routing is a deterministic function of the submission sequence and
@@ -89,7 +90,7 @@ def bench(smoke: bool = True, n_requests: int = 16, max_new: int = 8
     cluster_s = time.perf_counter() - t0
     st = cl.stats()
 
-    # -- parity oracle: unbatched reference (placement-independent) ------
+    # -- parity oracle: the engine's reference (placement-independent) ---
     refs = eng.generate_reference(stream())
     parity = (all(r.out_tokens == ref.out_tokens
                   for r, ref in zip(reqs1, refs))
@@ -136,7 +137,7 @@ def bench(smoke: bool = True, n_requests: int = 16, max_new: int = 8
     ]
 
     # functional gates — these hold on ANY host
-    assert parity, "cluster outputs diverged from the unbatched reference"
+    assert parity, "cluster outputs diverged from the reference"
     assert st["post_warmup_recompiles"] == 0, st["per_replica"]
     assert st_eng["compile"]["post_warmup_recompiles"] == 0, st_eng
     assert st["healthy"] == st["replicas"] == 2

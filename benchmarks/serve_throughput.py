@@ -1,13 +1,15 @@
 """Serve-throughput microbench: the scheduler acceptance gate.
 
 Drives a mixed-shape, mixed-format request stream through the
-token-level continuous-batching engine (warmed) and through the
-unbatched reference, reporting tokens/s, microbatch occupancy, mid-decode
-refills, prefix-cache reuse, bucket hit rate, padding waste, post-warmup
-recompiles, and batched-vs-unbatched parity.  Both paths are timed in
-the steady state (each runs the stream once untimed first — the
-reference pass doubling as the parity oracle) and the batched path must
-BEAT the reference: ``speedup >= 1.5`` is asserted here and floored at
+token-level continuous-batching engine (warmed) and through the engine's
+reference, reporting tokens/s, microbatch occupancy, mid-decode refills,
+prefix-cache reuse, bucket hit rate, padding waste, post-warmup
+recompiles, and batched-vs-reference parity.  Parity is checked against
+the reference at the serving width (``generate_reference``'s default,
+the exactness oracle); the throughput baseline is the reference at
+width 1, one request per call.  Both timed paths run in the steady state
+(each runs the stream once untimed first) and the batched path must
+BEAT the width-1 run: ``speedup >= 1.5`` is asserted here and floored at
 1.0 by ``compare.py`` in CI.  The CI ``perf-trajectory`` lane runs
 ``--smoke`` and records the rows to ``BENCH_serve.json`` (see
 ``bench_io``).
@@ -71,8 +73,7 @@ def bench(smoke: bool = True, n_requests: int = 12, max_new: int = 16
 
     # steady state on BOTH sides: each path runs the stream once untimed
     # (first-call costs — process-level jit/dispatch setup — fold into
-    # warmup, and the untimed reference pass doubles as the parity
-    # oracle), then the identical stream again, timed.  Stats rows report
+    # warmup), then the identical stream again, timed.  Stats rows report
     # the timed pass via counter deltas.
     stream = _requests(cfg.vocab, n=n_requests, alt_tag=alt_tag)
     eng.generate([Request(p, max_new_tokens=max_new, fset=f)
@@ -84,13 +85,14 @@ def bench(smoke: bool = True, n_requests: int = 12, max_new: int = 16
     serve_s = time.perf_counter() - t0
     st = eng.stats()
 
-    refs = eng.generate_reference(
-        [Request(np.asarray(p), max_new_tokens=max_new, fset=f)
-         for p, f in stream])
+    def ref_stream():
+        return [Request(np.asarray(p), max_new_tokens=max_new, fset=f)
+                for p, f in stream]
+
+    refs = eng.generate_reference(ref_stream())      # parity oracle
+    eng.generate_reference(ref_stream(), width=1)    # untimed warm pass
     t0 = time.perf_counter()
-    eng.generate_reference(
-        [Request(np.asarray(p), max_new_tokens=max_new, fset=f)
-         for p, f in stream])
+    eng.generate_reference(ref_stream(), width=1)
     unbatched_s = time.perf_counter() - t0
     parity = all(r.out_tokens == ref.out_tokens
                  for r, ref in zip(reqs, refs))
@@ -139,12 +141,12 @@ def bench(smoke: bool = True, n_requests: int = 12, max_new: int = 16
          f"parity={'ok' if parity else 'MISMATCH'};mode={eng.mode}"),
     ]
     # acceptance gate: the plan-warmed scheduler must batch, must not
-    # recompile, must match the unbatched engine per request — and, with
+    # recompile, must match the reference per request — and, with
     # continuous decode, batching must actually PAY: on-device sampling +
     # retire-and-refill + prefix reuse put the floor well above 1×
     assert st["compile"]["post_warmup_recompiles"] == 0, st["compile"]
     assert st["microbatches"]["multi_request"] >= 1, st["microbatches"]
-    assert parity, "batched outputs diverged from the unbatched reference"
+    assert parity, "batched outputs diverged from the reference"
     assert speedup >= 1.5, (
         f"batched serving is only {speedup:.2f}x the unbatched reference "
         f"(must be >= 1.5x)")

@@ -36,7 +36,15 @@ from __future__ import annotations
 import os
 from typing import Any, Optional
 
-__all__ = ["KNOWN_SETTINGS", "configure", "get", "get_bool", "reset"]
+__all__ = ["KNOWN_SETTINGS", "configure", "get", "get_bool", "reset",
+           "use_compile_cache"]
+
+#: JAX's persistent compile cache when ``JAX_COMPILATION_CACHE_DIR`` is
+#: unset: a fixed directory of the checkout (the path is part of what the
+#: cache keys on, so it must not move between runs)
+CHECKOUT_COMPILE_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
+        __file__)))), ".jax_cache")
 
 #: setting name -> (environment variable, default)
 KNOWN_SETTINGS: dict[str, tuple[str, Optional[str]]] = {
@@ -129,3 +137,17 @@ def _apply_obs() -> None:
         obs.configure(enabled=True)
     else:
         obs.configure(enabled=False)
+
+
+def use_compile_cache() -> str:
+    """Place JAX's persistent compile cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, wins and nothing is set in
+    code (JAX reads the variable itself); otherwise the cache lives in
+    :data:`CHECKOUT_COMPILE_CACHE`."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    jax.config.update("jax_compilation_cache_dir", CHECKOUT_COMPILE_CACHE)
+    return CHECKOUT_COMPILE_CACHE

@@ -38,24 +38,8 @@ from jax.sharding import PartitionSpec as Pspec
 
 from repro.core.formats import DEFAULT_FORMATS, FormatSet
 
-try:  # jax>=0.6
-    from jax import shard_map as _shard_map_fn
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map_fn
-
 #: local-update paths the SUMMA rank-update can execute
 LOCAL_PATHS = ("ref", "grouped")
-
-
-def _shard_map(f, mesh, in_specs, out_specs):
-    """shard_map with replication checking off (pallas_call has no
-    replication rule, and the psum-broadcast carry is device-varying)."""
-    try:
-        return _shard_map_fn(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False)
-    except TypeError:  # pragma: no cover — newer jax renamed the flag
-        return _shard_map_fn(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
 
 
 def _panel_owner_steps(K: int, tile: int, P: int, Q: int):
@@ -188,6 +172,7 @@ def _summa_impl(a_bufs, b_bufs, c_bufs, *, cls_a, cls_b, cls_c, tile, mesh,
     if local_path == "grouped":
         from repro.kernels.grouped_gemm import _grouped_class_call
         from repro.kernels.mp_gemm_tile import format_specs
+        from repro.kernels.ops import _interpret
         mt_loc, nt_loc = mloc // T, nloc // T
         a_vec = _segment_class_vector(a_cnt, fset)
         b_vec = _segment_class_vector(b_cnt, fset)
@@ -196,7 +181,7 @@ def _summa_impl(a_bufs, b_bufs, c_bufs, *, cls_a, cls_b, cls_c, tile, mesh,
         b_slots = tuple(jnp.asarray(t) for t in
                         _panel_slot_tables(b_vec, fset, transpose=True))
         specs = format_specs(fset)
-        interpret = jax.default_backend() != "tpu"
+        interpret = _interpret()
         # per-shard (ci, cj) coordinate tables, stacked host-side; counts
         # must be identical across shards (shard-balanced C map) so the
         # kernel grid is static under SPMD
@@ -320,11 +305,13 @@ def _summa_impl(a_bufs, b_bufs, c_bufs, *, cls_a, cls_b, cls_c, tile, mesh,
 
     spec2 = Pspec(row_ax, col_ax)
     rep = Pspec()
-    return _shard_map(
-        local_fn, mesh,
+    # replication checking off: pallas_call has no replication rule, and
+    # the psum-broadcast carry is device-varying
+    return jax.shard_map(
+        local_fn, mesh=mesh,
         in_specs=((spec2,) * nf, (spec2,) * nf, (spec2,) * nf, spec2,
                   table_specs, (rep,) * 4),
-        out_specs=(spec2,) * nf,
+        out_specs=(spec2,) * nf, check_vma=False,
     )(tuple(a_bufs), tuple(b_bufs), tuple(c_bufs), jnp.asarray(sel_c),
       tables, tuple(map(jnp.asarray, steps)))
 

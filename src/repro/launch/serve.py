@@ -21,7 +21,7 @@ import argparse
 import json
 
 
-def main():
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="internlm2-1.8b")
     ap.add_argument("--smoke", action="store_true")
@@ -81,7 +81,14 @@ def main():
                     help="record a repro.obs JSONL trace to this path "
                          "(a Perfetto-loadable .trace.json is written "
                          "alongside)")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
+
+
+def run(args):
+    """Init (or restore) the model, build the engine or cluster, warm it
+    and serve ``args.prompts``, printing one line per request.  Returns
+    ``(cfg, server, requests)``."""
+    import dataclasses
 
     import jax
     import numpy as np
@@ -91,16 +98,18 @@ def main():
     from repro.models import transformer as T
     from repro.serve import Cluster, Engine, Request, ServeConfig
 
+    repro.config.use_compile_cache()
     if args.trace:
         repro.configure(obs_trace=args.trace)
 
     load_all()
     cfg = get(args.arch)
     if args.smoke:
-        cfg = reduced(cfg, tp=2)
+        cfg = reduced(cfg)
+    # the engine shards no attention heads, so lay them out for one
+    # device: a wider tp would only duplicate KV heads and grow the cache
+    cfg = dataclasses.replace(cfg, tp=1)
     if args.formats:
-        import dataclasses
-
         from repro.core.formats import FormatSet
         cfg = dataclasses.replace(
             cfg, mp_formats=FormatSet.parse(args.formats).key())
@@ -169,10 +178,8 @@ def main():
                     fset=req_tag,
                     seed=args.request_seed + i)
             for i, p in enumerate(args.prompts)]
-    rejected = 0
     for i, r in enumerate(server.generate(reqs)):
         if r.error:
-            rejected += 1
             print(f"request {i}: prompt={np.asarray(r.prompt).tolist()} "
                   f"REJECTED — {r.error}")
             continue
@@ -181,8 +188,17 @@ def main():
               f"→ out={r.out_tokens}  "
               f"[bucket={r.bucket} padded_to={r.padded_to} "
               f"cold={r.cold}{where} latency={r.latency_s * 1e3:.0f}ms]")
+    return cfg, server, reqs
+
+
+def main():
+    args = parse_args()
+    import repro
+
+    _, server, reqs = run(args)
+    rejected = sum(bool(r.error) for r in reqs)
     st = server.stats()
-    if sc.replicas > 1:
+    if args.replicas > 1:
         print(f"served={st['requests']['served']} over "
               f"{st['healthy']}/{st['replicas']} healthy replicas, "
               f"post_warmup_recompiles={st['post_warmup_recompiles']}")

@@ -30,11 +30,13 @@ def _parse_ratio(s: str) -> tuple[float, float]:
     return hi, lo8
 
 
-def _parse():
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=512)
     ap.add_argument("--nrhs", type=int, default=1)
-    ap.add_argument("--tile", type=int, default=16)
+    ap.add_argument("--tile", type=int, default=16,
+                    help="precision-map tile; Pallas plans need a multiple "
+                         "of 128 on a TPU")
     ap.add_argument("--matrix", default="graded-spd",
                     choices=["graded-spd", "diag-dominant"])
     ap.add_argument("--cond", type=float, default=1e4,
@@ -74,22 +76,26 @@ def _parse():
                     help="record a repro.obs JSONL trace to this path "
                          "(a Perfetto-loadable .trace.json is written "
                          "alongside)")
-    return ap.parse_args()
+    return ap.parse_args(argv)
 
 
-def main() -> int:
-    args = _parse()
+def run(args):
+    """Build the operator, solve it and print the report.  Returns
+    ``(report, ok)``: ``ok`` is the launcher's exit gate — converged, zero
+    fresh mid-solve plan resolutions, and a storage saving where the
+    escalation mode promises one."""
     if args.devices:
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "") + " "
             f"--xla_force_host_platform_device_count={args.devices}").strip()
     import numpy as np
 
-    from repro import obs
+    from repro import config, obs
     from repro.core.formats import DEFAULT_FORMATS, FormatSet
     from repro.solve import (SolveConfig, diag_dominant, graded_spd,
                              rhs_for_solution, solve)
 
+    config.use_compile_cache()
     if args.trace:
         obs.configure(enabled=True, trace_path=args.trace)
 
@@ -163,6 +169,11 @@ def main() -> int:
     if not ok:
         print("FAILED: not converged, mid-solve retune, or no storage "
               "saving", file=sys.stderr)
+    return rep, ok
+
+
+def main() -> int:
+    _, ok = run(parse_args())
     return 0 if ok else 1
 
 

@@ -48,11 +48,13 @@ def main():
             f"--xla_force_host_platform_device_count={args.devices}").strip()
     import jax
 
+    from repro import config
     from repro.configs import get, load_all, reduced
     from repro.optim import adamw
     from repro.runtime.fault import RestartSignal
     from repro.train.trainer import TrainerConfig, train
 
+    config.use_compile_cache()
     load_all()
     cfg = get(args.arch)
     if args.smoke:

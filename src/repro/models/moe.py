@@ -205,11 +205,8 @@ def moe_block_sharded(params, x, *, top_k: int, mesh, ep: bool,
 
     Returns (y [B,S,d], aux scalar).  Capacity is per-data-shard.
     """
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
 
     B, S, d = x.shape
     E = params["router"].shape[1]

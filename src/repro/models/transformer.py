@@ -219,12 +219,23 @@ def init_model(key, cfg: ArchConfig) -> dict:
                 lkey, sub = jax.random.split(lkey)
                 row.append(_init_layer(sub, layer_cfg, mixer, ffn))
             stacked.append(row)
-        # stack across repeats: tree of [repeats, ...] leaves per position
+        # stack across repeats: tree of [repeats, ...] leaves per position.
+        # One leaf at a time, dropping the per-layer copies as they are
+        # stacked: stacking whole trees holds every layer twice, which at
+        # full width is the device-memory peak of init.
         seg = {}
         for pi in range(len(pattern)):
-            seg[f"pos{pi}"] = jax.tree.map(
-                lambda *xs: jnp.stack(xs), *[stacked[r][pi]
-                                             for r in range(repeats)])
+            rows = []
+            for r in range(repeats):
+                leaves, treedef = jax.tree.flatten(stacked[r][pi])
+                stacked[r][pi] = None
+                rows.append(leaves)
+            out = []
+            for li in range(len(rows[0])):
+                out.append(jnp.stack([row[li] for row in rows]))
+                for row in rows:
+                    row[li] = None
+            seg[f"pos{pi}"] = jax.tree.unflatten(treedef, out)
         segs.append(seg)
     params["blocks"] = segs
     return params

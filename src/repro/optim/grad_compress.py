@@ -14,11 +14,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-
-try:
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def ef_init(tree):

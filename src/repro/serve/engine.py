@@ -17,8 +17,8 @@ recompiles or re-plans.  Four mechanisms make batching *pay*:
   retires *mid-decode*: its tokens are materialized, its latency stamped
   at that step (not at microbatch end), and the next pending request for
   the same bucket is pulled into the freed row — its prefill chunked into
-  the decode stream as a batch-1 call — so finished requests never squat
-  in their slots while neighbours keep decoding.
+  the decode stream as a call at the microbatch width — so finished
+  requests never squat in their slots while neighbours keep decoding.
 * **Block-paged prefix reuse.**  Each bucket has a prefix point
   ``P = pad_len // 2`` aligned down to the KV page size; KV for positions
   ``0..P-1`` is cached as ref-counted fixed-size *pages* keyed by a
@@ -45,8 +45,15 @@ residency, per-request latency).
 Exactness: microbatches are *right*-padded, so under causal attention a
 request's real tokens never attend padding; decode threads per-request
 positions (RoPE), per-row cache slots, and a KV visibility mask through
-``forward_decode``.  Full-attention non-MoE families are therefore
-bit-exact with unbatched serving ("masked" mode) — including refilled
+``forward_decode``; generated tokens' KV is written over the padding, at
+each row's true position, so attention reduces over the same cache layout
+as unpadded decoding.  Every model executable — prefill, refill (all the
+refills of one retirement pass share one call), decode and the reference
+— runs at the one microbatch width ``max_batch``: XLA's result for a row
+depends on how many rows the matmul has (on CPU and TPU alike), though
+not on what the other rows hold.  Full-attention non-MoE families are
+therefore bit-exact with one-request-at-a-time serving ("masked" mode,
+checked against ``generate_reference``) — including refilled
 rows, page-reused prefills, and chunked long-prompt prefills (a cached
 page is bit-identical to what a fresh prefill would produce; a chunked
 scan sees the same caches, tokens, and positions as a monolithic one).
@@ -298,20 +305,22 @@ class Engine:
         def decode_cont_fn(p, tok, caches, lengths, slots, active, temps,
                            keys, pad_len):
             # token-level continuous decode: every row carries its own
-            # cache slot (retire-and-refill) and PRNG stream; positions,
-            # visibility mask, sampling AND the slot advance all derive on
-            # device, so the steady-state loop feeds (tok, caches, slots)
+            # step counter (retire-and-refill) and PRNG stream; positions,
+            # visibility mask, sampling AND the counter advance all derive
+            # on device, so the steady-state loop feeds (tok, caches, slots)
             # straight back with zero per-step host->device transfers.
             # ``pad_len`` is traced: ONE executable per batch width serves
-            # every bucket length, configured or chunked-dynamic
+            # every bucket length, configured or chunked-dynamic.
+            # Each row writes its KV at its true position, over the
+            # prompt's right padding, so attention reduces over the same
+            # cache layout as unpadded decoding (reduction order follows
+            # the layout; a gap would change the rounding)
             note()
             positions = lengths + slots - pad_len
-            kv_pos = jnp.arange(self.max_seq)
-            kv_valid = ((kv_pos[None, :] < lengths[:, None])
-                        | ((kv_pos[None, :] >= pad_len)
-                           & (kv_pos[None, :] <= slots[:, None])))
+            kv_valid = (jnp.arange(self.max_seq)[None, :]
+                        <= positions[:, None])
             logits, caches = T.forward_decode(p, cfg, tok, caches,
-                                              positions, slot=slots,
+                                              positions, slot=positions,
                                               kv_valid=kv_valid)
             n = slots - pad_len + 1
             nxt = _sample_tokens(logits[:, 0], temps, keys, n)
@@ -319,7 +328,7 @@ class Engine:
 
         def decode_sample_fn(p, tok, caches, position, temps, keys, n):
             # shared-scalar-position decode + sampling: equal mode and the
-            # unbatched reference
+            # reference
             note()
             logits, caches = T.forward_decode(p, cfg, tok, caches, position)
             nxt = _sample_tokens(logits[:, 0], temps, keys, n)
@@ -331,8 +340,8 @@ class Engine:
         self._decode_sample = jax.jit(decode_sample_fn)
 
         # KV data movement helpers (no model graph → not trace-counted):
-        # slice one page out of a cache row / scatter a page or a whole
-        # batch-1 cache into a row of the batch cache
+        # slice one page out of a cache row / scatter a page into a row /
+        # copy the masked rows of a refill cache into the batch cache
         def extract_page_fn(caches, row, start, width):
             def one(c):
                 r = jax.lax.dynamic_slice_in_dim(c, row, 1, axis=1)
@@ -347,16 +356,15 @@ class Engine:
                     c, s.astype(c.dtype), at)
             return jax.tree.map(one, caches, page)
 
-        def scatter_row_fn(caches, slab, row):
+        def copy_rows_fn(caches, src, mask):
             def one(c, s):
-                at = (jnp.int32(0), row) + (jnp.int32(0),) * (c.ndim - 2)
-                return jax.lax.dynamic_update_slice(
-                    c, s.astype(c.dtype), at)
-            return jax.tree.map(one, caches, slab)
+                m = mask.reshape((1, -1) + (1,) * (c.ndim - 2))
+                return jnp.where(m, s.astype(c.dtype), c)
+            return jax.tree.map(one, caches, src)
 
         self._extract_page = jax.jit(extract_page_fn, static_argnums=(3,))
         self._scatter_page = jax.jit(scatter_page_fn)
-        self._scatter_row = jax.jit(scatter_row_fn)
+        self._copy_rows = jax.jit(copy_rows_fn)
         self._base_key = jax.random.PRNGKey(config.rng_seed)
 
     def _req_key(self, req: Request) -> np.ndarray:
@@ -410,8 +418,7 @@ class Engine:
                               batch=bucket.batch):
                     self._compile_bucket(key, bucket.batch)
                 bucket.warmed = True
-                plans = {**plan_table.get((key.fset, 1), {}),
-                         **plan_table.get((key.fset, bucket.batch), {})}
+                plans = plan_table.get((key.fset, bucket.batch), {})
                 bucket.paths = tuple({p.path for p in plans.values()})
                 report[str(key)] = {"paths": sorted(bucket.paths)}
             if self._chunk and keys:
@@ -434,8 +441,8 @@ class Engine:
     def _compile_bucket(self, key: BucketKey, batch: int) -> None:
         """Trace+compile every executable the bucket can dispatch in the
         steady state on dummy data (jit caches all of them): full prefill,
-        suffix prefill (page reuse), the continuous decode step, and —
-        when refill is on — their batch-1 refill twins."""
+        suffix prefill (page reuse) and the continuous decode step.
+        Refills run these same executables."""
         params = self.variants[key.fset]
         S = key.pad_len
         toks = jnp.zeros((batch, S), jnp.int32)
@@ -457,14 +464,8 @@ class Engine:
                     params, toks[:, P:], caches, lengths, temps, kvec,
                     jnp.int32(P))
             if self.refill_enabled:
-                c1 = T.init_cache(self.cfg, 1, self.max_seq)
-                t1, c1 = self._prefill(params, toks[:1], c1, lengths[:1],
-                                       temps[:1], kvec[:1])
-                if P:
-                    t1, c1 = self._prefill_sfx(
-                        params, toks[:1, P:], c1, lengths[:1], temps[:1],
-                        kvec[:1], jnp.int32(P))
-                caches = self._scatter_row(caches, c1, jnp.int32(0))
+                caches = self._copy_rows(caches, caches,
+                                         jnp.zeros((batch,), bool))
             slots = jnp.full((batch,), S, jnp.int32)
             active = jnp.ones((batch,), jnp.int32)
             out = self._decode_cont(params, tok0[:, None], caches, lengths,
@@ -477,9 +478,9 @@ class Engine:
         jax.block_until_ready(out[0])
 
     def _compile_chunk(self, fset: str) -> None:
-        """Compile the ``[B, C]`` (and refill ``[1, C]``) chunk-prefill
-        executables.  The traced start offset means these two cover every
-        chunk of every long bucket; the traced-pad decode step compiled by
+        """Compile the ``[B, C]`` chunk-prefill executable.  The traced
+        start offset means it covers every chunk of every long bucket and
+        every refill; the traced-pad decode step compiled by
         ``_compile_bucket`` already covers long-bucket decoding."""
         params = self.variants[fset]
         C = self._chunk
@@ -491,10 +492,6 @@ class Engine:
         caches = T.init_cache(self.cfg, B, self.max_seq)
         tok, _ = self._prefill_sfx(params, toks, caches, lengths, temps,
                                    kvec, jnp.int32(0))
-        if self.refill_enabled:
-            c1 = T.init_cache(self.cfg, 1, self.max_seq)
-            tok, _ = self._prefill_sfx(params, toks[:1], c1, lengths[:1],
-                                       temps[:1], kvec[:1], jnp.int32(0))
         jax.block_until_ready(tok)
 
     # ------------------------------------------------------------------
@@ -693,7 +690,7 @@ class Engine:
             caches = T.init_cache(self.cfg, B, self.max_seq)
             cur, caches = self._prefill_rows(
                 bucket, params, caches, toks, lengths, temps, keys,
-                n_real, P, rows)
+                range(n_real), P, rows)
             devbuf.append(cur)
 
             def process_retirements() -> bool:
@@ -708,25 +705,24 @@ class Engine:
                         return changed
                     changed = True
                     self._drain(devbuf, hist)
-                    cur_np = None
+                    refills = []
                     for i in ret:
                         self._finalize(rows[i], i, bucket, hist, S, t0)
-                        if not self.refill_enabled:
-                            continue
-                        nxt = self.scheduler.pop_pending(key)
-                        if nxt is None:
-                            continue
-                        first, caches = self._refill_slot(
-                            bucket, params, caches, i, nxt, toks, lengths,
+                        nxt = (self.scheduler.pop_pending(key)
+                               if self.refill_enabled else None)
+                        if nxt is not None:
+                            refills.append((i, nxt))
+                    if refills:
+                        first, caches = self._refill_rows(
+                            bucket, params, caches, refills, toks, lengths,
                             temps, keys, slots, rows, hist, P)
-                        if cur_np is None:
-                            # seed from the LIVE decode input, not hist[-1]:
-                            # a substitution made by an earlier iteration of
-                            # this pass (a refill that itself retired at
-                            # max_new_tokens == 1) exists only in ``cur``
-                            cur_np = np.asarray(cur).copy()
-                        cur_np[i] = first
-                    if cur_np is not None:
+                        # seed from the LIVE decode input, not hist[-1]: a
+                        # substitution made by an earlier iteration of this
+                        # pass (a refill that itself retired at
+                        # max_new_tokens == 1) exists only in ``cur``
+                        cur_np = np.asarray(cur).copy()
+                        for i, _ in refills:
+                            cur_np[i] = first[i]
                         cur = self._dev(cur_np)
 
             def sync_decode_state():
@@ -798,35 +794,36 @@ class Engine:
                                          jnp.int32(j * pt), pt))
 
     def _prefill_rows(self, bucket, params, caches, toks, lengths, temps,
-                      keys, n_real: int, P: int, rows: list):
-        """Microbatch prefill: chunked for long buckets; otherwise
-        suffix-only when every real row covers its page chain, else full
-        (which then feeds the page cache)."""
+                      keys, idx, P: int, rows: list, refill: bool = False):
+        """Prefill of the real rows ``idx`` (a new microbatch, or the
+        refills of one retirement pass): chunked for long buckets;
+        otherwise suffix-only when every real row covers its page chain,
+        else full (which then feeds the page cache)."""
         key = bucket.key
         B, S = toks.shape
+        idx = list(idx)
         if self._is_chunked(S):
             return self._prefill_chunked(bucket, params, caches, toks,
-                                         lengths, temps, keys, n_real,
-                                         rows)
+                                         lengths, temps, keys, idx, rows,
+                                         refill)
         digs = [self._row_digests(key.fset, toks, lengths, i, P)
-                for i in range(n_real)]
+                for i in idx]
         use_sfx = bool(digs) and all(
             d is not None and self.prefix.covers(d) for d in digs)
         lengths_j, temps_j, keys_j = (self._dev(lengths),
                                       self._dev(temps), self._dev(keys))
         with obs.span("serve.prefill", "serve", bucket=str(key), batch=B,
-                      pad_len=S, prefix_reuse=use_sfx):
+                      pad_len=S, prefix_reuse=use_sfx, refill=refill):
             if use_sfx:
-                for i in range(n_real):
+                for i, d in zip(idx, digs):
                     caches, rows[i].table = self._scatter_chain(
-                        caches, digs[i], i)
+                        caches, d, i)
                 cur, caches = self._prefill_sfx(
                     params, self._dev(toks[:, P:]), caches, lengths_j,
                     temps_j, keys_j, jnp.int32(P))
                 self.metrics.counter("serve.prefix.reused_prefills").inc()
                 bucket.padded_tokens += int(
-                    B * (S - P)
-                    - np.maximum(lengths[:n_real] - P, 0).sum())
+                    B * (S - P) - np.maximum(lengths[idx] - P, 0).sum())
             else:
                 # mixed hit/miss wave: rows whose chain IS cached still
                 # count per-row hits (mirroring the per-row lookups of the
@@ -835,7 +832,7 @@ class Engine:
                 # distinct uncovered chain counts ONE miss, matching the
                 # single insert it triggers below
                 missed: dict[tuple, int] = {}
-                for i, d in enumerate(digs):
+                for i, d in zip(idx, digs):
                     if d is None:
                         continue
                     if self.prefix.covers(d):
@@ -846,13 +843,14 @@ class Engine:
                 cur, caches = self._prefill(params, self._dev(toks),
                                             caches, lengths_j, temps_j,
                                             keys_j)
-                bucket.padded_tokens += int(B * S - lengths[:n_real].sum())
-                for i in missed.values():
-                    self._insert_chain_from_row(caches, digs[i], i)
+                bucket.padded_tokens += int(B * S - lengths[idx].sum())
+                for d, i in missed.items():
+                    self._insert_chain_from_row(caches, list(d), i)
         return cur, caches
 
     def _prefill_chunked(self, bucket, params, caches, toks, lengths,
-                         temps, keys, n_real: int, rows: list):
+                         temps, keys, idx: list, rows: list,
+                         refill: bool):
         """Long-prompt prefill through the pre-warmed ``[B, C]`` chunk
         executable with a traced position offset.  Every row of a chunked
         bucket has its last real token in the final chunk (bucketing
@@ -873,23 +871,23 @@ class Engine:
             # first sampled token must come from a fresh computation)
             digs = [page_digests(key.fset, toks[i], pt,
                                  limit=int(lengths[i]) - 1)
-                    for i in range(n_real)]
+                    for i in idx]
             covered = [len(self.prefix.chain(d)) * pt // C for d in digs]
             n_skip = min(min(c, n_chunks - 1) for c in covered)
         lengths_j, temps_j, keys_j = (self._dev(lengths),
                                       self._dev(temps), self._dev(keys))
         with obs.span("serve.prefill", "serve", bucket=str(key), batch=B,
-                      pad_len=S, prefix_reuse=n_skip > 0,
+                      pad_len=S, prefix_reuse=n_skip > 0, refill=refill,
                       chunks=n_chunks, chunks_skipped=n_skip):
             missed: dict[tuple, int] = {}
             if n_skip:
                 npages = n_skip * C // pt
-                for i in range(n_real):
+                for i, d in zip(idx, digs):
                     caches, rows[i].table = self._scatter_chain(
-                        caches, digs[i][:npages], i)
+                        caches, d[:npages], i)
                 self.metrics.counter("serve.prefix.reused_prefills").inc()
             elif paged:
-                for i, d in enumerate(digs):
+                for i, d in zip(idx, digs):
                     if not d:
                         continue
                     if self.prefix.covers(d):
@@ -905,102 +903,48 @@ class Engine:
             self.metrics.counter("serve.chunked_prefills").inc()
             bucket.padded_tokens += int(
                 B * (S - n_skip * C)
-                - np.maximum(lengths[:n_real] - n_skip * C, 0).sum())
-            for i in missed.values():
-                self._insert_chain_from_row(caches, digs[i], i)
+                - np.maximum(lengths[idx] - n_skip * C, 0).sum())
+            for d, i in missed.items():
+                self._insert_chain_from_row(caches, list(d), i)
         return cur, caches
 
-    def _refill_slot(self, bucket, params, caches, i: int, nxt: Request,
+    def _refill_rows(self, bucket, params, caches, refills: list,
                      toks, lengths, temps, keys, slots, rows, hist,
                      P: int):
-        """Pull ``nxt`` into freed slot ``i`` mid-decode: batch-1 prefill
-        (page-reused / chunked as its bucket demands) chunked into the
-        decode stream, then scatter its cache row into the batch."""
-        key = bucket.key
-        S = toks.shape[1]
-        L2 = len(nxt.prompt)
-        toks[i, :] = 0
-        toks[i, :L2] = nxt.prompt
-        lengths[i] = L2
-        temps[i] = nxt.temperature
-        keys[i] = self._req_key(nxt)
-        c1 = T.init_cache(self.cfg, 1, self.max_seq)
-        l_j = self._dev(lengths[i:i + 1])
-        t_j = self._dev(temps[i:i + 1])
-        k_j = self._dev(keys[i:i + 1])
-        table = None
-        if self._is_chunked(S):
-            tk, c1, table = self._refill_chunked(
-                bucket, params, c1, toks, lengths, i, l_j, t_j, k_j)
-        else:
-            dig = self._row_digests(key.fset, toks, lengths, i, P)
-            use_sfx = dig is not None and self.prefix.covers(dig)
-            with obs.span("serve.prefill", "serve", bucket=str(key),
-                          batch=1, pad_len=S, prefix_reuse=use_sfx,
-                          refill_slot=i):
-                if use_sfx:
-                    c1, table = self._scatter_chain(c1, dig, 0)
-                    tk, c1 = self._prefill_sfx(
-                        params, self._dev(toks[i:i + 1, P:]), c1, l_j,
-                        t_j, k_j, jnp.int32(P))
-                    bucket.padded_tokens += int((S - P) - max(L2 - P, 0))
-                else:
-                    if dig is not None:
-                        self.prefix.misses += 1
-                    tk, c1 = self._prefill(
-                        params, self._dev(toks[i:i + 1]), c1, l_j, t_j,
-                        k_j)
-                    bucket.padded_tokens += int(S - L2)
-                    if dig is not None:
-                        self._insert_chain_from_row(c1, dig, 0)
-        caches = self._scatter_row(caches, c1, jnp.int32(i))
-        slots[i] = S
-        rows[i] = _Row(req=nxt, length=L2, emitted=1, join=len(hist),
-                       first_tok=int(np.asarray(tk)[0]), active=True,
-                       cold=False, table=table)
-        self.metrics.counter("serve.refills").inc()
-        if obs.is_enabled():
-            obs.event("serve.refill", "serve", bucket=str(key), slot=i,
-                      length=L2, prefix_reuse=table is not None)
-        return rows[i].first_tok, caches
-
-    def _refill_chunked(self, bucket, params, c1, toks, lengths, i: int,
-                        l_j, t_j, k_j):
-        """Batch-1 chunked prefill for a refill into a long bucket — the
-        same pre-warmed ``[1, C]`` executable at every chunk offset."""
-        key = bucket.key
-        S = toks.shape[1]
-        C = self._chunk
-        n_chunks = S // C
-        L2 = int(lengths[i])
-        pt = self.pool.page_tokens if self.prefix is not None else 0
-        paged = bool(pt) and C % pt == 0
-        digs = (page_digests(key.fset, toks[i], pt, limit=L2 - 1)
-                if paged else [])
-        n_skip = 0
-        table = None
-        if digs:
-            n_skip = min(len(self.prefix.chain(digs)) * pt // C,
-                         n_chunks - 1)
-        with obs.span("serve.prefill", "serve", bucket=str(key), batch=1,
-                      pad_len=S, prefix_reuse=n_skip > 0, refill_slot=i,
-                      chunks=n_chunks, chunks_skipped=n_skip):
-            if n_skip:
-                c1, table = self._scatter_chain(
-                    c1, digs[:n_skip * C // pt], 0)
-            elif digs:
-                self.prefix.misses += 1
-            tk = None
-            for c in range(n_skip, n_chunks):
-                tk, c1 = self._prefill_sfx(
-                    params, self._dev(toks[i:i + 1, c * C:(c + 1) * C]),
-                    c1, l_j, t_j, k_j, jnp.int32(c * C))
-            self.metrics.counter("serve.chunked_prefills").inc()
-            bucket.padded_tokens += int((S - n_skip * C)
-                                        - max(L2 - n_skip * C, 0))
-            if digs and not n_skip:
-                self._insert_chain_from_row(c1, digs, 0)
-        return tk, c1, table
+        """Pull pending requests into the slots one retirement pass freed
+        (``refills`` = ``[(slot, request)]``): ONE prefill of a fresh
+        cache through the microbatch's own executables (page-reused /
+        chunked as the bucket demands; the rows not being refilled are
+        recomputed and discarded), then copy the refilled rows into the
+        batch cache.  Returns the sampled first tokens (host ``[B]``) and
+        the caches."""
+        B, S = toks.shape
+        idx = [i for i, _ in refills]
+        for i, nxt in refills:
+            L2 = len(nxt.prompt)
+            toks[i, :] = 0
+            toks[i, :L2] = nxt.prompt
+            lengths[i] = L2
+            temps[i] = nxt.temperature
+            keys[i] = self._req_key(nxt)
+            slots[i] = S
+            rows[i] = _Row(req=nxt, length=L2, emitted=1, join=len(hist),
+                           active=True, cold=False)
+        c1 = T.init_cache(self.cfg, B, self.max_seq)
+        tk, c1 = self._prefill_rows(bucket, params, c1, toks, lengths,
+                                    temps, keys, idx, P, rows, refill=True)
+        mask = np.zeros((B,), bool)
+        mask[idx] = True
+        caches = self._copy_rows(caches, c1, self._dev(mask))
+        tk = np.asarray(tk)
+        for i, nxt in refills:
+            rows[i].first_tok = int(tk[i])
+            self.metrics.counter("serve.refills").inc()
+            if obs.is_enabled():
+                obs.event("serve.refill", "serve", bucket=str(bucket.key),
+                          slot=i, length=len(nxt.prompt),
+                          prefix_reuse=rows[i].table is not None)
+        return tk, caches
 
     # -- equal mode: shared-position continuous decode --------------------
 
@@ -1082,38 +1026,47 @@ class Engine:
             m.counter("serve.microbatch.multi").inc()
 
     # ------------------------------------------------------------------
-    # unbatched reference (ground truth for parity tests / debugging)
+    # one-request-at-a-time reference (ground truth for parity tests)
     # ------------------------------------------------------------------
 
-    def generate_reference(self, requests: list[Request]) -> list[Request]:
+    def generate_reference(self, requests: list[Request],
+                           width: Optional[int] = None) -> list[Request]:
         """Serve requests one at a time with no padding — the semantic
         baseline the scheduler path must match (masked/equal modes are
         bit-exact for greedy AND sampled decoding: the same fused sampler
-        runs under the same per-request PRNG stream).  Its compiles are
-        counted under ``reference_traces``, not as recompiles of the
-        serving path."""
+        runs under the same per-request PRNG stream).  Each request fills
+        every row of a ``width``-wide call.  The default, ``max_batch``,
+        runs the serving path's matmul shapes (see the module docstring):
+        that is the exactness oracle.  ``width=1`` is the plain
+        one-request-at-a-time run that throughput benchmarks time as the
+        unbatched baseline; its tokens may differ from the oracle's in
+        the last bits of a logit.  Compiles are counted under
+        ``reference_traces``, not as recompiles of the serving path."""
         self._ref_active = True
         try:
-            return self._generate_reference(requests)
+            return self._generate_reference(requests,
+                                            width or self.max_batch)
         finally:
             self._ref_active = False
 
-    def _generate_reference(self, requests: list[Request]) -> list[Request]:
+    def _generate_reference(self, requests: list[Request],
+                            B: int) -> list[Request]:
         for r in requests:
             params = self.variants[r.fset]
             L = len(r.prompt)
-            toks = jnp.asarray(np.asarray(r.prompt, np.int32)[None])
-            caches = T.init_cache(self.cfg, 1, self.max_seq)
-            temps = jnp.asarray([float(r.temperature)], jnp.float32)
-            keys = jnp.asarray(self._req_key(r)[None])
+            toks = jnp.asarray(np.tile(np.asarray(r.prompt, np.int32),
+                                       (B, 1)))
+            caches = T.init_cache(self.cfg, B, self.max_seq)
+            temps = jnp.full((B,), float(r.temperature), jnp.float32)
+            keys = jnp.asarray(np.tile(self._req_key(r), (B, 1)))
             tok, caches = self._prefill(params, toks, caches,
-                                        jnp.full((1,), L, jnp.int32),
+                                        jnp.full((B,), L, jnp.int32),
                                         temps, keys)
             out = [tok]
             for step in range(1, r.max_new_tokens):
                 tok, caches = self._decode_sample(
                     params, tok[:, None], caches, jnp.int32(L + step - 1),
-                    temps, keys, jnp.full((1,), step, jnp.int32))
+                    temps, keys, jnp.full((B,), step, jnp.int32))
                 out.append(tok)
             r.out_tokens = [int(np.asarray(t)[0]) for t in out]
             r.done = True

@@ -257,11 +257,9 @@ def _decide_compute(cfg: SolveConfig, mt: int, rt: int
 
 
 def _summa_cache_size() -> int:
+    """Compiled SUMMA executables so far (the recompile audit's counter)."""
     from repro.core.summa import _summa_impl
-    try:
-        return int(_summa_impl._cache_size())
-    except Exception:  # pragma: no cover — private jit API moved
-        return 0
+    return int(_summa_impl._cache_size())
 
 
 class _Solver:

@@ -15,7 +15,8 @@ times), and overhead charges each kernel grid step (dominant in CPU
 interpret mode).
 
 ``validate_plan`` rejects plans that violate MXU alignment (% 128 on real
-TPUs), shape divisibility, path applicability, or the VMEM working-set
+TPUs), the device's Pallas dtype support (no float16 on v5e), shape
+divisibility, path applicability, or the VMEM working-set
 budget — the VMEM formulas previously lived only in kernel docstrings
 (kernels/mp_gemm_tile.py, kernels/ksplit_gemm.py, kernels/grouped_gemm.py)
 and are centralized here.
@@ -184,6 +185,18 @@ def plan_vmem_bytes(plan: GemmPlan, prob: GemmProblem) -> int:
     return 0  # XLA paths: no explicit VMEM contract
 
 
+def kernel_dtypes(fset: FormatSet) -> set[str]:
+    """Names of the dtypes a set's Pallas kernels load, compute or slice
+    in (buffer, compute and split-slice dtypes of every format)."""
+    out = set()
+    for f in fset.formats():
+        out.update(np.dtype(d).name
+                   for d in (f.buffer_dtype, f.compute_dtype))
+        if isinstance(f, SplitFormat):
+            out.add(np.dtype(f.slice_dtype).name)
+    return out
+
+
 def validate_plan(plan: GemmPlan, prob: GemmProblem, dev: DeviceSpec,
                   vmem_fraction: float = 0.9) -> list[str]:
     """Reasons this plan cannot run (empty list = valid)."""
@@ -251,6 +264,10 @@ def validate_plan(plan: GemmPlan, prob: GemmProblem, dev: DeviceSpec,
             if b % dev.alignment:
                 bad.append(f"{name}={b} violates MXU alignment "
                            f"% {dev.alignment}")
+        refused = kernel_dtypes(prob.fset) & set(dev.pallas_bad_dtypes)
+        if refused:
+            bad.append(f"{dev.kind} Pallas kernels cannot use "
+                       f"{sorted(refused)}")
     vmem = plan_vmem_bytes(plan, prob)
     budget = int(dev.vmem_bytes * vmem_fraction)
     if vmem > budget:

@@ -45,6 +45,9 @@ class DeviceSpec:
     low_tflops: float               # peak LOW-class (bf16) matmul TFLOP/s
     task_overhead_s: float          # fixed cost per kernel grid step
     interpret: bool                 # Pallas runs in interpret mode here
+    #: dtype names this device's Pallas compiler cannot load, compute or
+    #: slice in; plans whose format set touches one stay on the XLA paths
+    pallas_bad_dtypes: tuple[str, ...] = ()
 
     def format_cost(self, name: str) -> float:
         """Relative MXU passes of a tile task at format ``name`` here."""
@@ -67,12 +70,14 @@ class DeviceSpec:
         return w
 
 
-def _tpu(kind, vmem_mb, gbps, tflops, overhead=2e-6) -> DeviceSpec:
+def _tpu(kind, vmem_mb, gbps, tflops, overhead=2e-6,
+         pallas_bad_dtypes=()) -> DeviceSpec:
     return DeviceSpec(
         kind=kind, mxu=(128, 128), alignment=128,
         vmem_bytes=vmem_mb * 2**20, smem_bytes=64 * 2**10,
         hbm_gbps=gbps, low_tflops=tflops,
-        task_overhead_s=overhead, interpret=False)
+        task_overhead_s=overhead, interpret=False,
+        pallas_bad_dtypes=tuple(pallas_bad_dtypes))
 
 
 #: Known accelerators.  Numbers are public peak specs (bf16 / HBM); they feed
@@ -81,7 +86,10 @@ def _tpu(kind, vmem_mb, gbps, tflops, overhead=2e-6) -> DeviceSpec:
 #: cores, fp8 at double rate on A100 …) live in the format registry.
 DEVICE_TABLE: dict[str, DeviceSpec] = {
     "tpu-v4": _tpu("tpu-v4", vmem_mb=16, gbps=1228.0, tflops=275.0),
-    "tpu-v5e": _tpu("tpu-v5e", vmem_mb=16, gbps=819.0, tflops=197.0),
+    # Mosaic for v5e refuses float16 loads ("Invalid vector type for
+    # load") and bf16->f16 casts (tests/test_tpu_compile.py)
+    "tpu-v5e": _tpu("tpu-v5e", vmem_mb=16, gbps=819.0, tflops=197.0,
+                    pallas_bad_dtypes=("float16",)),
     "tpu-v5p": _tpu("tpu-v5p", vmem_mb=16, gbps=2765.0, tflops=459.0),
     "tpu-v6e": _tpu("tpu-v6e", vmem_mb=32, gbps=1640.0, tflops=918.0),
     "gpu-a100": DeviceSpec(
@@ -143,7 +151,9 @@ def detect_device(device: "jax.Device | None" = None) -> DeviceSpec:
         for pat, key in _KIND_PATTERNS:
             if pat in kind:
                 return DEVICE_TABLE[key]
-        # unknown accelerator: assume the most conservative TPU entry
-        return DEVICE_TABLE["tpu-v5e" if device.platform == "tpu"
-                            else "gpu-a100"]
+        # no assumed peaks for hardware the table does not describe
+        raise KeyError(
+            f"{device.platform} device_kind {device.device_kind!r} is not "
+            f"in the device table {sorted(DEVICE_TABLE)}; add a DeviceSpec "
+            "or force one with repro.configure(device=...)")
     return DEVICE_TABLE["cpu-interpret"]

@@ -458,15 +458,9 @@ def resolve_plans_for_buckets(params_by_tag: dict, buckets, *,
     alike (``pad_len`` is accepted so a future bulk-prefill path can add
     its ``batch * pad_len`` hint without changing callers).
 
-    Deliberately NOT resolved: ``m = 1``.  Continuous decode chunks
-    batch-1 refill prefills (and the unbatched reference) through the same
-    linears, but those must stay on ``linear_matmul``'s registry-miss XLA
-    path — XLA ksplit is row-wise bit-identical across batch sizes, which
-    is what makes a refilled row (prefilled at m=1) token-exact with the
-    initially batched rows (prefilled at m=batch).  Registering an m=1
-    plan could legally select ``ksplit_pallas`` with ``bm=1`` and fork the
-    serve stream onto two kernels with different rounding, silently
-    breaking masked-mode's batched-vs-unbatched parity guarantee.
+    No other ``m`` occurs on the serving path: refills and the parity
+    reference run at the microbatch width too, since a row's result
+    depends on the matmul's row count (see ``repro.serve.engine``).
 
     Returns ``{(tag, batch): {plan_cache_key: GemmPlan}}``; every resolved
     plan is also loaded into the in-memory registry, so the engine's traces

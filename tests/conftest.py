@@ -27,12 +27,8 @@ def _force_host_devices() -> None:
     if "xla_force_host_platform_device_count" in os.environ.get(
             "XLA_FLAGS", ""):
         return  # respect an explicit setting (e.g. the CI multi-device lane)
-    try:
-        from jax._src import xla_bridge as xb
-        initialized = xb.backends_are_initialized()
-    except Exception:  # private API moved — don't guess, leave env alone
-        return
-    if not initialized:
+    from jax._src import xla_bridge as xb
+    if not xb.backends_are_initialized():
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "") + " " + _FLAG).strip()
 

@@ -100,6 +100,19 @@ def _general_problem(size, ratio, ratio8, seed, fset):
     return a, b, A, B, C, (pa, pb, pc)
 
 
+def _shares(ratios, ratios8=(0.0, 0.25)):
+    """Joint (ratio_high, ratio_low8) draws whose role shares fit in one
+    map (a sum above 1 is rejected by the map builder)."""
+    return st.sampled_from([(r, r8) for r in ratios for r8 in ratios8
+                            if r + r8 <= 1.0])
+
+
+def _low8_share(fset, ratio):
+    """Q share for a drawn D share: 0.25 where the set has a LOW8 role
+    and the D share leaves room for it."""
+    return min(0.25, 1.0 - ratio) if fset.low8 is not None else 0.0
+
+
 def _check_path(path, size, ratio, ratio8=0.0, seed=0,
                 fset=DEFAULT_FORMATS):
     a, b, A, B, C, (pa, pb, pc) = _general_problem(
@@ -114,26 +127,21 @@ def _check_path(path, size, ratio, ratio8=0.0, seed=0,
 
 @settings(max_examples=8, deadline=None)
 @given(size=st.sampled_from([32, 64]),
-       ratio=st.sampled_from([0.0, 0.25, 0.5, 1.0]),
-       ratio8=st.sampled_from([0.0, 0.25]), seed=st.integers(0, 3))
-def test_ref_path_within_bound(size, ratio, ratio8, seed):
-    _check_path("ref", size, ratio, ratio8, seed)
+       shares=_shares([0.0, 0.25, 0.5, 1.0]), seed=st.integers(0, 3))
+def test_ref_path_within_bound(size, shares, seed):
+    _check_path("ref", size, *shares, seed)
 
 
 @settings(max_examples=6, deadline=None)
-@given(size=st.sampled_from([32, 64]),
-       ratio=st.sampled_from([0.0, 0.5, 1.0]),
-       ratio8=st.sampled_from([0.0, 0.25]))
-def test_tile_path_within_bound(size, ratio, ratio8):
-    _check_path("tile", size, ratio, ratio8)
+@given(size=st.sampled_from([32, 64]), shares=_shares([0.0, 0.5, 1.0]))
+def test_tile_path_within_bound(size, shares):
+    _check_path("tile", size, *shares)
 
 
 @settings(max_examples=6, deadline=None)
-@given(size=st.sampled_from([32, 64]),
-       ratio=st.sampled_from([0.0, 0.5, 1.0]),
-       ratio8=st.sampled_from([0.0, 0.25]))
-def test_grouped_path_within_bound(size, ratio, ratio8):
-    _check_path("grouped", size, ratio, ratio8)
+@given(size=st.sampled_from([32, 64]), shares=_shares([0.0, 0.5, 1.0]))
+def test_grouped_path_within_bound(size, shares):
+    _check_path("grouped", size, *shares)
 
 
 def _ksplit_problem(size, ratio, seed, fset):
@@ -199,8 +207,7 @@ def test_split_paths_within_bound(size, ratio, path, which, seed):
     """split2/split3 compound HIGH classes meet their registry-derived
     (recovered-roundoff) bound on both the oracle and the kernel path."""
     fset = SPLIT_SETS[which]
-    ratio8 = 0.25 if fset.low8 is not None else 0.0
-    _check_path(path, size, ratio, ratio8, seed, fset)
+    _check_path(path, size, ratio, _low8_share(fset, ratio), seed, fset)
 
 
 def test_split_bound_is_fp32_grade():
@@ -248,8 +255,7 @@ def test_int_paths_within_bound(size, ratio, path, which, seed):
     replaces mantissa roundoff, scaled to the per-tile absmax envelope)
     on the general dispatch paths."""
     fset = INT_SETS[which]
-    ratio8 = 0.25 if fset.low8 is not None else 0.0
-    _check_path(path, size, ratio, ratio8, seed, fset)
+    _check_path(path, size, ratio, _low8_share(fset, ratio), seed, fset)
 
 
 @settings(max_examples=4, deadline=None)
@@ -334,12 +340,11 @@ def _summa_within_bound(P, Q, fset, ratio=0.5, ratio8=0.0, seed=0):
 
 
 @settings(max_examples=6, deadline=None)
-@given(ratio=st.sampled_from([0.0, 0.5, 1.0]),
-       ratio8=st.sampled_from([0.0, 0.25]), seed=st.integers(0, 2))
-def test_summa_1x1_within_bound(ratio, ratio8, seed):
+@given(shares=_shares([0.0, 0.5, 1.0]), seed=st.integers(0, 2))
+def test_summa_1x1_within_bound(shares, seed):
     """SUMMA semantics are mesh-size independent; a 1×1 grid runs the full
     slab/scan machinery on a single device."""
-    _summa_within_bound(1, 1, DEFAULT_FORMATS, ratio, ratio8, seed)
+    _summa_within_bound(1, 1, DEFAULT_FORMATS, *shares, seed)
 
 
 @pytest.mark.parametrize("fs", ["fp8_e4m3+bf16+fp32", "fp8_e5m2+fp16+fp32",

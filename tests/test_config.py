@@ -117,3 +117,24 @@ def test_env_bootstrap_untouched_by_facade(monkeypatch):
     monkeypatch.setenv("REPRO_TUNE_CACHE", "/env/plans.json")
     repro.configure(tune_cache="/override.json")
     assert os.environ["REPRO_TUNE_CACHE"] == "/env/plans.json"
+
+
+def test_compile_cache_follows_env_var(tmp_path, monkeypatch):
+    """JAX reads JAX_COMPILATION_CACHE_DIR itself: nothing is set in code."""
+    import jax
+    updates = []
+    monkeypatch.setattr(jax.config, "update", lambda *a: updates.append(a))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert config.use_compile_cache() == str(tmp_path)
+    assert updates == []
+
+
+def test_compile_cache_defaults_to_checkout(monkeypatch):
+    import jax
+    updates = []
+    monkeypatch.setattr(jax.config, "update", lambda *a: updates.append(a))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(root, ".jax_cache")
+    assert config.use_compile_cache() == want
+    assert updates == [("jax_compilation_cache_dir", want)]

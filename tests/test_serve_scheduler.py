@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from repro.configs import load_all, reduced
 from repro.models import transformer as T
@@ -159,6 +160,57 @@ def test_warmed_mixed_shape_stream_exact_and_no_recompiles():
     assert st["compile"]["post_warmup_recompiles"] == 0
     assert st["microbatches"]["multi_request"] >= 1
     assert st["bucket_misses"] == 0 and st["bucket_hits"] >= 1
+
+
+def test_parity_holds_at_width_where_rounding_decides_tokens():
+    """At d_model 256 / vocab 8192 a random model's greedy argmax flips
+    on last-bit differences, so batched serving stays token-exact only if
+    every row sees the reference's arithmetic — here, the same matmul row
+    count for prefill, refill and reference."""
+    load_all()
+    from repro.configs import get
+    cfg = dataclasses.replace(get("internlm2-1.8b"), n_layers=2,
+                              d_model=256, n_heads=4, n_kv_heads=2,
+                              d_ff=1024, vocab=8192, tp=1)
+    params = T.init_model(jax.random.PRNGKey(0), cfg)
+    eng = Engine(cfg, params, ServeConfig(buckets=(16, 32, 64),
+                                          max_batch=4, max_seq=80))
+    eng.warmup()
+    rng = np.random.default_rng(1)
+    prompts = [rng.integers(0, cfg.vocab, n).tolist()
+               for n in np.random.default_rng(0).integers(8, 61, size=8)]
+    reqs = _reqs(prompts, max_new=16)
+    eng.generate(reqs)
+    refs = eng.generate_reference(_reqs(prompts, max_new=16))
+    assert [r.out_tokens for r in reqs] == [r.out_tokens for r in refs]
+    assert eng.stats()["compile"]["post_warmup_recompiles"] == 0
+
+
+def test_decode_writes_kv_over_the_padding():
+    """A right-padded row's generated KV lands at its true position, so its
+    cache matches unpadded decoding slot by slot.  A TPU reduces attention
+    lane-parallel, so KV placed after the padding would change the
+    rounding even though the padding is masked out."""
+    cfg, params, eng = _mk_engine(max_batch=2, max_seq=32, buckets=(8,))
+    L, S, B = 3, 8, 2
+    toks = np.zeros((B, S), np.int32)
+    toks[:, :L] = [5, 6, 7]
+    lengths = jnp.full((B,), L, jnp.int32)
+    temps = jnp.zeros((B,), jnp.float32)
+    keys = jnp.zeros((B, 2), jnp.uint32)
+    tok, caches = eng._prefill(params, jnp.asarray(toks),
+                               T.init_cache(cfg, B, 32), lengths, temps,
+                               keys)
+    _, caches, _ = eng._decode_cont(
+        params, tok[:, None], caches, lengths, jnp.full((B,), S, jnp.int32),
+        jnp.ones((B,), jnp.int32), temps, keys, jnp.int32(S))
+    rtok, ref = eng._prefill(params, jnp.asarray(toks[:, :L]),
+                             T.init_cache(cfg, B, 32), lengths, temps, keys)
+    _, ref = eng._decode_sample(params, rtok[:, None], ref, jnp.int32(L),
+                                temps, keys, jnp.ones((B,), jnp.int32))
+    for got, want in zip(jax.tree.leaves(caches), jax.tree.leaves(ref)):
+        np.testing.assert_array_equal(np.asarray(got)[:, :, :L + 1],
+                                      np.asarray(want)[:, :, :L + 1])
 
 
 def test_cold_bucket_fallback_records_miss_not_crash():
@@ -371,6 +423,30 @@ def test_double_refill_with_instant_retire_stays_exact():
     assert st["microbatches"]["refills"] == 3
     assert st["requests"]["served"] == 5
     assert st["compile"]["post_warmup_recompiles"] == 0
+
+
+def test_refills_of_one_retirement_pass_share_one_prefill():
+    # slots freed in the same retirement pass are refilled by ONE prefill
+    # at the microbatch width, not one call per slot: the schedule of
+    # test_double_refill_with_instant_retire_stays_exact needs the wave's
+    # prefill, one call for the two slots freed together, and one for
+    # the slot its instant retirement frees
+    cfg, params, eng = _mk_engine(max_batch=2, max_seq=32, buckets=(4,))
+    eng.warmup()
+    calls = []
+    for name in ("_prefill", "_prefill_sfx"):
+        fn = getattr(eng, name)
+
+        def counted(*a, _fn=fn, **k):
+            calls.append(a[1].shape)
+            return _fn(*a, **k)
+        setattr(eng, name, counted)
+    prompts = [[1, 2, 3], [4, 5], [6, 7, 8, 9], [2, 2, 2], [3, 1]]
+    reqs = [Request(np.asarray(p, np.int32), max_new_tokens=n)
+            for p, n in zip(prompts, [1, 1, 1, 3, 2])]
+    eng.generate(reqs)
+    assert eng.stats()["microbatches"]["refills"] == 3
+    assert calls == [(2, 4)] * 3
 
 
 def test_prefix_reuse_prefill_exact_and_counted():

@@ -104,6 +104,52 @@ def test_detect_device_forced(monkeypatch):
         detect_device()
 
 
+class _FakeDevice:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+def test_detect_device_maps_known_kinds():
+    assert detect_device(_FakeDevice("tpu", "TPU v5 lite")).kind == "tpu-v5e"
+    assert detect_device(_FakeDevice("tpu", "TPU v6e")).kind == "tpu-v6e"
+    assert detect_device(_FakeDevice("cpu", "cpu")).kind == "cpu-interpret"
+
+
+@pytest.mark.parametrize("platform,kind", [("tpu", "TPU v99 mega"),
+                                           ("gpu", "Radeon Pro W7900")])
+def test_detect_device_refuses_unknown_accelerator(platform, kind):
+    """No peak rates are assumed for hardware the table does not hold."""
+    with pytest.raises(KeyError, match=kind):
+        detect_device(_FakeDevice(platform, kind))
+
+
+def _uniform_problem(fset, code, nt=4, t=128):
+    full = np.full((nt, nt), code, np.int8)
+    return GemmProblem.from_maps(full, full, full, t, fset=fset)
+
+
+@pytest.mark.parametrize("path,fs", [
+    ("tile", "fp8_e5m2+fp16+fp32"), ("grouped", "fp8_e5m2+fp16+fp32"),
+    ("ksplit_pallas", "fp16+fp32"), ("split", "bf16+split2_fp16")])
+def test_fp16_sets_stay_off_v5e_pallas_paths(path, fs):
+    """float16 in a set's buffers, compute or split slices makes every
+    Pallas path invalid on v5e (Mosaic refuses it there), for that reason
+    alone; the XLA paths keep serving the set."""
+    from repro.core.formats import format_set
+    fset = format_set(*fs.split("+"))
+    prob = _uniform_problem(
+        fset, fset.high if path == "split" else fset.low)
+    plan = GemmPlan(path=path)
+    bad = validate_plan(plan, prob, V5E)
+    assert len(bad) == 1 and "float16" in bad[0], bad
+    assert not validate_plan(plan, prob, DEVICE_TABLE["tpu-v6e"])
+    xla = ("ref",) if path == "split" else ("ref", "ksplit_xla")
+    for p in xla:
+        assert not validate_plan(GemmPlan(path=p), prob, V5E)
+    assert {p.path for p in TS.candidate_plans(prob, V5E)} \
+        <= {"ref", "ksplit_xla"}
+
+
 # ---------------------------------------------------------------------------
 # plan cache
 # ---------------------------------------------------------------------------
