@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.formats import DEFAULT_FORMATS, FormatSet, SplitFormat
 from repro.core.layout import MPMatrix
 
@@ -53,22 +54,29 @@ def mp_gemm_ref(a: MPMatrix, b: MPMatrix, c: MPMatrix,
     """Oracle implementation: one dense dot per C-precision class present,
     then a per-tile select.  Numerically exact w.r.t. the tile semantics;
     not the performance path (classes × MNK flops)."""
-    ad, bd = _storage_dense(a), _storage_dense(b)
-    cd = _storage_dense(c)
+    with obs.span("gemm.storage_view", "gemm"):
+        ad, bd = _storage_dense(a), _storage_dense(b)
+        cd = _storage_dense(c)
     fset = c.fset
     classes = sorted({int(v) for v in np.unique(c.cls.arr)})
     per_class = {}
     for cc in classes:
         fmt = fset.fmt(cc)
-        acc = _class_dot(ad, bd, fmt)
-        per_class[cc] = alpha * acc + beta * cd
-    sel = jnp.asarray(_expand(c.cls.arr, c.tile))
-    out = jnp.zeros_like(cd)
-    for cc in classes:
-        out = jnp.where(sel == cc, per_class[cc], out)
+        with obs.span("gemm.class_dot", "gemm", format=fmt.name):
+            acc = _class_dot(ad, bd, fmt)
+            per_class[cc] = alpha * acc + beta * cd
+    with obs.span("gemm.expand_map", "gemm"):
+        host_sel = _expand(c.cls.arr, c.tile)
+    with obs.span("gemm.upload_map", "gemm"):
+        sel = jnp.asarray(host_sel)
+    with obs.span("gemm.select", "gemm"):
+        out = jnp.zeros_like(cd)
+        for cc in classes:
+            out = jnp.where(sel == cc, per_class[cc], out)
     # store back in C's per-tile precision
-    return MPMatrix.from_dense(
-        out[: c.shape[0], : c.shape[1]], c.cls.arr, c.tile, fset)
+    with obs.span("gemm.store", "gemm"):
+        return MPMatrix.from_dense(
+            out[: c.shape[0], : c.shape[1]], c.cls.arr, c.tile, fset)
 
 
 def mp_gemm_tilewise_ref(a: MPMatrix, b: MPMatrix, c: MPMatrix,

@@ -351,35 +351,32 @@ def summa_mp_gemm(a, b, c=None, *, mesh, axes: Sequence[str] = ("row", "col"),
         _dispatch.DISPATCH_METRIC, path=plan.path, op=prob.op,
         formats=prob.formats).inc()
 
-    def run():
+    # host-side lens on the device-side panel loop: one span for the whole
+    # distributed GEMM plus, with JSONL tracing on, an instant per k-panel
+    # carrying the *static* owner schedule (the scan body itself runs under
+    # jit/SPMD, so per-step wall-clock is not observable from here — the
+    # schedule is)
+    row_ax, col_ax = tuple(axes)
+    K = prob.k                      # padded K = tile-grid extent × tile
+    with obs.span("summa.gemm", "summa", op=prob.op, path=plan.path,
+                  m=prob.m, n=prob.n, k=prob.k, formats=prob.formats,
+                  steps=K // a.tile):
+        if obs.is_enabled():
+            try:
+                qa, la, pb, lb = _panel_owner_steps(
+                    K, a.tile, mesh.shape[row_ax], mesh.shape[col_ax])
+                for s in range(len(qa)):
+                    obs.event("summa.panel", "summa", step=s,
+                              a_owner_col=int(qa[s]), a_local=int(la[s]),
+                              b_owner_row=int(pb[s]), b_local=int(lb[s]))
+            except ValueError:
+                pass     # _summa_impl raises the descriptive error below
         out_bufs = _summa_impl(
             tuple(a.bufs), tuple(b.bufs), tuple(c.bufs),
             cls_a=a.cls, cls_b=b.cls, cls_c=c.cls, tile=a.tile, mesh=mesh,
             axes=tuple(axes), alpha=alpha, beta=beta, fset=fset,
             local_path=plan.path)
         return MPMatrix(tuple(out_bufs), c.cls, c.tile, c.shape, fset)
-
-    if not obs.is_enabled():
-        return run()
-    # host-side lens on the device-side panel loop: one span for the whole
-    # distributed GEMM plus an instant per k-panel carrying the *static*
-    # owner schedule (the scan body itself runs under jit/SPMD, so per-step
-    # wall-clock is not observable from here — the schedule is)
-    row_ax, col_ax = tuple(axes)
-    K = prob.k                      # padded K = tile-grid extent × tile
-    with obs.span("summa.gemm", "summa", op=prob.op, path=plan.path,
-                  m=prob.m, n=prob.n, k=prob.k, formats=prob.formats,
-                  steps=K // a.tile):
-        try:
-            qa, la, pb, lb = _panel_owner_steps(
-                K, a.tile, mesh.shape[row_ax], mesh.shape[col_ax])
-            for s in range(len(qa)):
-                obs.event("summa.panel", "summa", step=s,
-                          a_owner_col=int(qa[s]), a_local=int(la[s]),
-                          b_owner_row=int(pb[s]), b_local=int(lb[s]))
-        except ValueError:
-            pass               # run() raises the descriptive error below
-        return run()
 
 
 def summa_collective_bytes(M: int, N: int, K: int, tile: int, P: int, Q: int,

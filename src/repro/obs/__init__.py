@@ -10,9 +10,11 @@ volume); this package is our reproduction's equivalent lens:
   into instead of ad-hoc module-global dicts;
 * a structured span/event :class:`~repro.obs.trace.Tracer` emitting
   JSON-lines that double as Chrome ``trace_event`` dicts (open the export
-  in Perfetto or ``chrome://tracing``) — **zero-cost when disabled**: the
-  default tracer is a shared no-op singleton, so the instrumented hot
-  paths pay one attribute load and a constant-time call.
+  in Perfetto or ``chrome://tracing``).  The default tracer writes no
+  file; its spans are bare ``jax.profiler.TraceAnnotation``s;
+* every span, JSONL on or off, lands on the JAX profiler's timeline as
+  ``repro.<name>``, so a profiler session attributes device idle time to
+  what the host was doing.
 
 Facade::
 
@@ -22,7 +24,7 @@ Facade::
         ...
     obs.event("serve.admit", "serve", bucket="S16/default")
     obs.metrics_registry().counter("dispatch.calls", path="grouped").inc()
-    obs.configure(enabled=False)          # back to the no-op tracer
+    obs.configure(enabled=False)          # back to profiler-only spans
 
 Environment bootstrap: setting ``REPRO_OBS_TRACE=<path>`` (or
 ``REPRO_OBS=1`` for an in-memory tracer) enables tracing at import time,
@@ -57,9 +59,8 @@ def configure(enabled: bool = True, trace_path: str | None = None,
     ``enabled=True`` with a ``trace_path`` streams JSONL events to that
     file; without a path, events collect in ``tracer().buffer`` (tests,
     short-lived tools).  ``enabled=False`` closes any active tracer and
-    restores the no-op singleton — the default state, under which no trace
-    file is ever created and instrumented code paths are bitwise-identical
-    to uninstrumented ones.
+    restores the JSONL-free singleton — the default state, under which no
+    trace file is ever created and spans are profiler annotations only.
     """
     global _TRACER
     if _TRACER is not NULL_TRACER:
@@ -77,7 +78,8 @@ def tracer() -> Tracer | NullTracer:
 
 
 def span(name: str, cat: str, **args):
-    """Context manager tracing one complete span (no-op when disabled)."""
+    """Context manager tracing one complete span: always a profiler
+    annotation ``repro.<name>``, and a JSONL event when enabled."""
     return _TRACER.span(name, cat, **args)
 
 

@@ -20,9 +20,16 @@ schema drift is a CI failure, not silent rot:
   ``solve.escalate`` spans carrying promoted-tile coordinates
 * ``train`` — tune-once setup (``train.tune_setup``, ``train.step_config``)
 
-The disabled path is :class:`NullTracer`: ``span()`` returns a shared
-no-op context manager and ``event()`` returns immediately — no file, no
-allocation, no timestamps (``repro.obs.configure`` swaps the singleton).
+Every span also enters a ``jax.profiler.TraceAnnotation`` named
+``repro.<name>`` (args become its stats), with or without a JSONL sink, so
+any profiler session shows the program's phases on the device's clock.
+While no session records, a span is a shared no-op after one check of
+the profiler; instants and counters stay JSONL-only (the profiler has no
+instant).
+
+The JSONL-disabled path is :class:`NullTracer`: ``span()`` returns only
+the profiler annotation and ``event()`` returns immediately — no file, no
+timestamps (``repro.obs.configure`` swaps the singleton).
 """
 from __future__ import annotations
 
@@ -40,6 +47,11 @@ REQUIRED_FIELDS = ("name", "cat", "ph", "ts", "pid", "tid")
 #: event phases the schema admits (complete span / instant / counter)
 PHASES = ("X", "i", "C")
 
+#: prefix of a span's name on the profiler's timeline
+PROFILER_PREFIX = "repro."
+
+_TraceAnnotation = None     # jax.profiler.TraceAnnotation, on first use
+
 
 class _NullSpan:
     __slots__ = ()
@@ -54,15 +66,28 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+def annotation(name: str, args: dict):
+    """The profiler annotation ``repro.<name>`` of one span, or a shared
+    no-op while no profiler session records (jax is imported on the first
+    span, so importing ``repro.obs`` stays stdlib-only)."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+        _TraceAnnotation = TraceAnnotation
+    if not _TraceAnnotation.is_enabled():
+        return _NULL_SPAN
+    return _TraceAnnotation(PROFILER_PREFIX + name, **args)
+
+
 class NullTracer:
-    """The zero-cost disabled tracer: every method is a constant-time
-    no-op returning shared singletons."""
+    """The JSONL-disabled tracer: spans reach the profiler only, and every
+    other method is a constant-time no-op."""
 
     enabled = False
     path = None
 
     def span(self, name, cat, **args):
-        return _NULL_SPAN
+        return annotation(name, args)
 
     def event(self, name, cat, **args):
         return None
@@ -81,20 +106,25 @@ NULL_TRACER = NullTracer()
 
 
 class _Span:
-    """Context manager emitting one complete ("X") event on exit."""
+    """Context manager emitting one complete ("X") event on exit, inside
+    the span's profiler annotation."""
 
-    __slots__ = ("_tr", "_name", "_cat", "_args", "_t0")
+    __slots__ = ("_tr", "_name", "_cat", "_args", "_t0", "_ann")
 
     def __init__(self, tr, name, cat, args):
         self._tr, self._name, self._cat, self._args = tr, name, cat, args
 
     def __enter__(self):
+        self._ann = annotation(self._name, self._args)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        self._tr._emit_span(self._name, self._cat, self._t0,
-                            time.perf_counter(), self._args)
+        t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        self._tr._emit_span(self._name, self._cat, self._t0, t1,
+                            self._args)
         return False
 
 

@@ -577,7 +577,8 @@ class Engine:
         masked-mode microbatch keeps refilling from its bucket's queue
         until the whole stream for that bucket drains)."""
         while True:
-            mb = self.scheduler.next_microbatch()
+            with obs.span("serve.schedule", "serve"):
+                mb = self.scheduler.next_microbatch()
             if mb is None:
                 return
             bucket, reqs = mb
@@ -628,7 +629,8 @@ class Engine:
         """Materialize pending device token vectors into the host history
         (the engine's only device→host sync, paid at retirement)."""
         if devbuf:
-            hist.extend(np.stack([np.asarray(t) for t in devbuf]))
+            with obs.span("serve.drain", "serve", steps=len(devbuf)):
+                hist.extend(np.stack([np.asarray(t) for t in devbuf]))
             devbuf.clear()
 
     @staticmethod
@@ -704,36 +706,31 @@ class Engine:
                     if not ret:
                         return changed
                     changed = True
-                    self._drain(devbuf, hist)
-                    refills = []
-                    for i in ret:
-                        self._finalize(rows[i], i, bucket, hist, S, t0)
-                        nxt = (self.scheduler.pop_pending(key)
-                               if self.refill_enabled else None)
-                        if nxt is not None:
-                            refills.append((i, nxt))
-                    if refills:
-                        first, caches = self._refill_rows(
-                            bucket, params, caches, refills, toks, lengths,
-                            temps, keys, slots, rows, hist, P)
-                        # seed from the LIVE decode input, not hist[-1]: a
-                        # substitution made by an earlier iteration of this
-                        # pass (a refill that itself retired at
-                        # max_new_tokens == 1) exists only in ``cur``
-                        cur_np = np.asarray(cur).copy()
-                        for i, _ in refills:
-                            cur_np[i] = first[i]
-                        cur = self._dev(cur_np)
+                    with obs.span("serve.retire_pass", "serve",
+                                  retired=len(ret)):
+                        self._drain(devbuf, hist)
+                        refills = []
+                        for i in ret:
+                            self._finalize(rows[i], i, bucket, hist, S, t0)
+                            nxt = (self.scheduler.pop_pending(key)
+                                   if self.refill_enabled else None)
+                            if nxt is not None:
+                                refills.append((i, nxt))
+                        if refills:
+                            cur, caches = self._refill_rows(
+                                bucket, params, caches, cur, refills, toks,
+                                lengths, temps, keys, slots, rows, hist, P)
 
             def sync_decode_state():
                 # snapshot the host staging buffers onto the device; paid
                 # only at microbatch start and after a retire/refill event
                 # mutates them — steady-state steps run device-resident
-                return (self._dev(lengths), self._dev(slots),
-                        self._dev(np.array(
-                            [1 if r.active else 0 for r in rows],
-                            np.int32)),
-                        self._dev(temps), self._dev(keys))
+                with obs.span("serve.sync_state", "serve"):
+                    return (self._dev(lengths), self._dev(slots),
+                            self._dev(np.array(
+                                [1 if r.active else 0 for r in rows],
+                                np.int32)),
+                            self._dev(temps), self._dev(keys))
 
             with obs.span("serve.decode", "serve", bucket=str(key)):
                 process_retirements()
@@ -754,7 +751,6 @@ class Engine:
                         lengths_d, slots_d, active_d, temps_d, keys_d = \
                             sync_decode_state()
                 m.counter("serve.decode_steps").inc(steps)
-        m.counter("serve.decode_time_s").inc(time.perf_counter() - t0)
         bucket.warmed = True        # compiled now — next time is a hit
         m.histogram("serve.microbatch.size").observe(n_real)
         if n_real > 1:
@@ -908,7 +904,7 @@ class Engine:
                 self._insert_chain_from_row(caches, list(d), i)
         return cur, caches
 
-    def _refill_rows(self, bucket, params, caches, refills: list,
+    def _refill_rows(self, bucket, params, caches, cur, refills: list,
                      toks, lengths, temps, keys, slots, rows, hist,
                      P: int):
         """Pull pending requests into the slots one retirement pass freed
@@ -916,35 +912,41 @@ class Engine:
         cache through the microbatch's own executables (page-reused /
         chunked as the bucket demands; the rows not being refilled are
         recomputed and discarded), then copy the refilled rows into the
-        batch cache.  Returns the sampled first tokens (host ``[B]``) and
-        the caches."""
+        batch cache.  Returns the decode input ``cur`` with each refilled
+        row seeded by its sampled first token, and the caches."""
         B, S = toks.shape
         idx = [i for i, _ in refills]
-        for i, nxt in refills:
-            L2 = len(nxt.prompt)
-            toks[i, :] = 0
-            toks[i, :L2] = nxt.prompt
-            lengths[i] = L2
-            temps[i] = nxt.temperature
-            keys[i] = self._req_key(nxt)
-            slots[i] = S
-            rows[i] = _Row(req=nxt, length=L2, emitted=1, join=len(hist),
-                           active=True, cold=False)
-        c1 = T.init_cache(self.cfg, B, self.max_seq)
-        tk, c1 = self._prefill_rows(bucket, params, c1, toks, lengths,
-                                    temps, keys, idx, P, rows, refill=True)
-        mask = np.zeros((B,), bool)
-        mask[idx] = True
-        caches = self._copy_rows(caches, c1, self._dev(mask))
-        tk = np.asarray(tk)
-        for i, nxt in refills:
-            rows[i].first_tok = int(tk[i])
-            self.metrics.counter("serve.refills").inc()
-            if obs.is_enabled():
-                obs.event("serve.refill", "serve", bucket=str(bucket.key),
-                          slot=i, length=len(nxt.prompt),
-                          prefix_reuse=rows[i].table is not None)
-        return tk, caches
+        with obs.span("serve.refill", "serve", bucket=str(bucket.key),
+                      slots=idx,
+                      lengths=[len(nxt.prompt) for _, nxt in refills]):
+            for i, nxt in refills:
+                L2 = len(nxt.prompt)
+                toks[i, :] = 0
+                toks[i, :L2] = nxt.prompt
+                lengths[i] = L2
+                temps[i] = nxt.temperature
+                keys[i] = self._req_key(nxt)
+                slots[i] = S
+                rows[i] = _Row(req=nxt, length=L2, emitted=1,
+                               join=len(hist), active=True, cold=False)
+            c1 = T.init_cache(self.cfg, B, self.max_seq)
+            tk, c1 = self._prefill_rows(bucket, params, c1, toks, lengths,
+                                        temps, keys, idx, P, rows,
+                                        refill=True)
+            mask = np.zeros((B,), bool)
+            mask[idx] = True
+            caches = self._copy_rows(caches, c1, self._dev(mask))
+            tk = np.asarray(tk)
+            # seed from the LIVE decode input, not hist[-1]: a substitution
+            # made by an earlier iteration of this retirement pass (a
+            # refill that itself retired at max_new_tokens == 1) exists
+            # only in ``cur``
+            cur_np = np.asarray(cur).copy()
+            for i in idx:
+                rows[i].first_tok = int(tk[i])
+                cur_np[i] = tk[i]
+            self.metrics.counter("serve.refills").inc(len(idx))
+            return self._dev(cur_np), caches
 
     # -- equal mode: shared-position continuous decode --------------------
 
@@ -989,9 +991,10 @@ class Engine:
                    and rows[i].emitted >= rows[i].req.max_new_tokens]
             if not ret:
                 return
-            self._drain(devbuf, hist)
-            for i in ret:
-                self._finalize(rows[i], i, bucket, hist, S, t0)
+            with obs.span("serve.retire_pass", "serve", retired=len(ret)):
+                self._drain(devbuf, hist)
+                for i in ret:
+                    self._finalize(rows[i], i, bucket, hist, S, t0)
 
         with obs.span("serve.microbatch", "serve", bucket=str(key),
                       n_real=n_real, batch=B, pad_len=S, warm=was_warm):
@@ -1019,7 +1022,6 @@ class Engine:
                             row.emitted += 1
                     t += 1
                     process_retirements()
-        m.counter("serve.decode_time_s").inc(time.perf_counter() - t0)
         bucket.warmed = True
         m.histogram("serve.microbatch.size").observe(n_real)
         if n_real > 1:
@@ -1115,7 +1117,6 @@ class Engine:
                     m.value("serve.post_warmup_recompiles")),
             },
             "decode_steps": int(m.value("serve.decode_steps")),
-            "decode_time_s": m.value("serve.decode_time_s"),
             "chunked_prefills": int(m.value("serve.chunked_prefills")),
             "latency_s": {
                 "mean": lat.mean,

@@ -157,8 +157,9 @@ def _exec_split(plan, a, b, c, alpha, beta):
 
 def _exec_grouped(plan, a, b, c, alpha, beta):
     t = a.tile
-    ac = CompactMPMatrix.from_dense(a.to_dense(), a.cls.arr, t, a.fset)
-    bc = CompactMPMatrix.from_dense(b.to_dense(), b.cls.arr, t, b.fset)
+    with obs.span("gemm.prepare", "gemm", path=plan.path):
+        ac = CompactMPMatrix.from_dense(a.to_dense(), a.cls.arr, t, a.fset)
+        bc = CompactMPMatrix.from_dense(b.to_dense(), b.cls.arr, t, b.fset)
     out = ops.grouped_mp_gemm(ac, bc, c.cls.arr)
     dense = out.to_dense()[: c.shape[0], : c.shape[1]]
     return MPMatrix.from_dense(dense, c.cls.arr, t, c.fset)
@@ -177,17 +178,21 @@ def _finish_c(y, c: MPMatrix, alpha, beta):
 
 
 def _exec_ksplit_xla(plan, a, b, c, alpha, beta):
-    y = ksplit_matmul(a.to_dense(), _ksplit_weight(b))
+    with obs.span("gemm.prepare", "gemm", path=plan.path):
+        w = _ksplit_weight(b)
+    y = ksplit_matmul(a.to_dense(), w)
     return _finish_c(y, c, alpha, beta)
 
 
 def _exec_ksplit_pallas(plan, a, b, c, alpha, beta):
-    w = _ksplit_weight(b)
-    x = a.to_dense()
-    # the kernel consumes x with class-contiguous K columns (storage order)
-    parts = KSplitWeight.k_partition(w.k_cls.arr, w.tile, w.fset)
-    xp = jnp.concatenate(
-        [x[:, jnp.asarray(idx)] for idx in parts if len(idx)], axis=-1)
+    with obs.span("gemm.prepare", "gemm", path=plan.path):
+        w = _ksplit_weight(b)
+        x = a.to_dense()
+        # the kernel consumes x with class-contiguous K columns (storage
+        # order)
+        parts = KSplitWeight.k_partition(w.k_cls.arr, w.tile, w.fset)
+        xp = jnp.concatenate(
+            [x[:, jnp.asarray(idx)] for idx in parts if len(idx)], axis=-1)
     y = ops.ksplit_matmul_kernel(xp, w, bm=plan.bm, bn=plan.bn, bk=plan.bk)
     return _finish_c(y, c, alpha, beta)
 
@@ -256,23 +261,22 @@ def mp_matmul(a: MPMatrix, b: MPMatrix, c: MPMatrix | None = None, *,
     With no explicit ``plan``, resolution order is in-memory registry →
     persisted plan cache (``autotune`` winners) → analytical cost model.
     """
-    a, b, c = canonical_operands(a, b, c)
-    prob = problem_of(a, b, c, alpha=alpha, beta=beta)
-    if plan is None:
-        plan, _ = resolve_plan(prob)
-    else:
-        bad = validate_plan(plan, prob, detect_device())
-        if bad:
-            raise ValueError(f"plan {plan.key()} invalid: {bad}")
+    with obs.span("gemm.resolve", "gemm"):
+        a, b, c = canonical_operands(a, b, c)
+        prob = problem_of(a, b, c, alpha=alpha, beta=beta)
+        if plan is None:
+            plan, _ = resolve_plan(prob)
+        else:
+            bad = validate_plan(plan, prob, detect_device())
+            if bad:
+                raise ValueError(f"plan {plan.key()} invalid: {bad}")
     obs.metrics_registry().counter(
         DISPATCH_METRIC, path=plan.path, op=prob.op,
         formats=prob.formats).inc()
-    if obs.is_enabled():
-        with obs.span("gemm.dispatch", "gemm", path=plan.path,
-                      m=prob.m, n=prob.n, k=prob.k, op=prob.op,
-                      formats=prob.formats):
-            return execute_plan(plan, a, b, c, alpha=alpha, beta=beta)
-    return execute_plan(plan, a, b, c, alpha=alpha, beta=beta)
+    with obs.span("gemm.dispatch", "gemm", path=plan.path,
+                  m=prob.m, n=prob.n, k=prob.k, op=prob.op,
+                  formats=prob.formats):
+        return execute_plan(plan, a, b, c, alpha=alpha, beta=beta)
 
 
 # ---------------------------------------------------------------------------

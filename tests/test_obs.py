@@ -172,6 +172,125 @@ def test_dispatch_bitwise_identical_with_tracing(tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# spans on the profiler's timeline (JSONL on or off)
+# ---------------------------------------------------------------------------
+
+def _profiled(fn, trace_dir):
+    """Run ``fn`` under a ``jax.profiler`` session; return its result and
+    the host events named ``repro.*`` as ``(start, end, name)``."""
+    import glob
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                     recursive=True)[-1]
+    spans = [(e.start_ns, e.start_ns + e.duration_ns, e.name)
+             for plane in jax.profiler.ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith(OT.PROFILER_PREFIX)]
+    return out, spans
+
+
+def _nested(spans, child: str, parent: str) -> bool:
+    """Every ``child`` span lies inside some ``parent`` span (and there is
+    at least one child)."""
+    kids = [(s, e) for s, e, n in spans if n == child]
+    outer = [(s, e) for s, e, n in spans if n == parent]
+    return bool(kids) and all(any(ps <= s and e <= pe for ps, pe in outer)
+                              for s, e in kids)
+
+
+def test_ref_gemm_spans_reach_the_profiler(tmp_path):
+    """With JSONL tracing off, a ``ref`` GEMM's host phases appear on the
+    profiler's timeline, the map expansion and upload inside dispatch."""
+    from repro.tune import dispatch as TD
+    from repro.tune.costmodel import GemmPlan
+    A, C = _tiny_mp_operands()
+    obs.configure(enabled=False)
+    _, spans = _profiled(
+        lambda: jax.block_until_ready(
+            TD.mp_matmul(A, A, C, plan=GemmPlan("ref")).bufs),
+        tmp_path)
+    names = {n for _, _, n in spans}
+    assert {"repro.gemm.resolve", "repro.gemm.storage_view",
+            "repro.gemm.class_dot", "repro.gemm.select",
+            "repro.gemm.store"} <= names
+    for child in ("repro.gemm.expand_map", "repro.gemm.upload_map",
+                  "repro.gemm.class_dot"):
+        assert _nested(spans, child, "repro.gemm.dispatch"), child
+    # one class-dot span per output class present (the map is 50/50)
+    assert sum(n == "repro.gemm.class_dot" for _, _, n in spans) == 2
+
+
+def test_serve_refill_and_drain_nest_in_the_retirement_pass(tmp_path):
+    from repro.configs import load_all, reduced
+    from repro.models import transformer as T
+    from repro.serve import ServeConfig
+    from repro.serve.engine import Engine, Request
+
+    cfg = reduced(load_all()["llama3-8b"], tp=2)
+    params = T.init_model(jax.random.PRNGKey(0), cfg)
+    eng = Engine(cfg, params,
+                 ServeConfig(max_batch=2, max_seq=32, buckets=(4,)))
+    eng.warmup()
+    reqs = [Request(np.asarray(p, np.int32), max_new_tokens=n)
+            for p, n in zip([[1, 2, 3], [4, 5], [6, 7, 8], [2, 2]],
+                            [1, 3, 2, 2])]
+    _, spans = _profiled(lambda: eng.generate(reqs), tmp_path)
+    assert eng.stats()["microbatches"]["refills"] >= 1
+    assert all(r.done for r in reqs)
+    for child in ("repro.serve.refill", "repro.serve.drain"):
+        assert _nested(spans, child, "repro.serve.retire_pass"), child
+    # each refill runs one prefill of the freed rows
+    refills = [(s, e) for s, e, n in spans if n == "repro.serve.refill"]
+    assert all(sum(n == "repro.serve.prefill" and rs <= s and e <= re
+                   for s, e, n in spans) == 1 for rs, re in refills)
+    names = {n for _, _, n in spans}
+    assert {"repro.serve.schedule", "repro.serve.sync_state",
+            "repro.serve.microbatch", "repro.serve.decode"} <= names
+
+
+def test_jsonl_sink_still_clean_with_profiler_spans(tmp_path):
+    """The JSONL sink keeps its schema (hygiene passes) while the same
+    spans reach a profiler session; the instant stays JSONL-only."""
+    p = str(tmp_path / "t.jsonl")
+    obs.configure(enabled=True, trace_path=p)
+
+    def work():
+        with obs.span("solve.run", "solve", method="lu"):
+            with obs.span("gemm.dispatch", "gemm", path="ref"):
+                obs.event("plan.resolve", "plan", source="cache")
+
+    _, spans = _profiled(work, tmp_path / "prof")
+    obs.configure(enabled=False)
+    assert OH.validate_trace(p, min_span_types=2) == []
+    events = OT.read_events(p)
+    assert OT.span_types(events) == ["gemm.dispatch", "solve.run"]
+    assert _nested(spans, "repro.gemm.dispatch", "repro.solve.run")
+    assert "repro.plan.resolve" not in {n for _, _, n in spans}
+
+
+def test_spans_reach_the_profiler_with_jsonl_off(tmp_path):
+    obs.configure(enabled=False)
+
+    def work():
+        with obs.span("serve.microbatch", "serve", n_real=2, bucket="S8"):
+            with obs.span("serve.decode", "serve"):
+                pass
+
+    _, spans = _profiled(work, tmp_path)
+    assert obs.tracer() is OT.NULL_TRACER
+    assert _nested(spans, "repro.serve.decode", "repro.serve.microbatch")
+    assert not list(tmp_path.glob("*.jsonl"))
+
+
+# ---------------------------------------------------------------------------
 # dispatch resolution counters: registry-backed, compat API intact
 # ---------------------------------------------------------------------------
 
@@ -241,7 +360,7 @@ def test_engine_stats_shape_parity():
     assert set(st) == {"mode", "requests", "tokens", "padding_waste",
                        "microbatches", "bucket_hits", "bucket_misses",
                        "bucket_hit_rate", "compile", "decode_steps",
-                       "decode_time_s", "chunked_prefills", "latency_s",
+                       "chunked_prefills", "latency_s",
                        "prefix_cache", "kv_pages", "scheduler"}
     assert set(st["requests"]) == {"served", "rejected"}
     assert set(st["tokens"]) == {"prompt", "padded", "generated"}
