@@ -70,6 +70,31 @@ class _HashableMap:
         return f"_HashableMap{self.arr.shape}"
 
 
+def dense_of(bufs) -> jax.Array:
+    """Sum of per-format buffers at fp32: the padded dense view of an
+    ``MPMatrix`` (each tile is nonzero in one buffer only)."""
+    d = bufs[0].astype(jnp.float32)
+    for b in bufs[1:]:
+        d = d + b.astype(jnp.float32)
+    return d
+
+
+def tile_buffers(wp: jax.Array, cmap: jax.Array, tile: int,
+                 fset: FormatSet) -> tuple[jax.Array, ...]:
+    """One buffer per format code of ``fset`` for the padded fp32 ``wp``:
+    the tiles whose class in ``cmap`` (an int8 ``[mt, nt]`` device array,
+    traced or not) is that code go through the format's ``to_buffer``,
+    every other tile is zero.  The map is compared over a ``(mt, t, nt, t)``
+    view of ``wp``, so no element-wide map is built."""
+    mt, nt = cmap.shape
+    w4 = wp.reshape(mt, tile, nt, tile)
+    sel = cmap[:, None, :, None]
+    return tuple(
+        fset.fmt(code).to_buffer(
+            jnp.where(sel == code, w4, 0.0).reshape(wp.shape), tile=tile)
+        for code in fset.codes)
+
+
 # ---------------------------------------------------------------------------
 # MPMatrix — dense per-format buffers
 # ---------------------------------------------------------------------------
@@ -123,14 +148,8 @@ class MPMatrix:
                    fset: FormatSet = DEFAULT_FORMATS) -> "MPMatrix":
         cls_map = _check_codes(cls_map, fset)
         mt, nt = cls_map.shape
-        m, n = mt * tile, nt * tile
-        wp = _pad_to(w.astype(jnp.float32), m, n)
-        cmap = jnp.asarray(cls_map, jnp.int8)
-        sel = jnp.repeat(jnp.repeat(cmap, tile, 0), tile, 1)
-        bufs = tuple(
-            fset.fmt(code).to_buffer(jnp.where(sel == code, wp, 0.0),
-                                     tile=tile)
-            for code in fset.codes)
+        wp = _pad_to(w.astype(jnp.float32), mt * tile, nt * tile)
+        bufs = tile_buffers(wp, jnp.asarray(cls_map, jnp.int8), tile, fset)
         return cls(bufs, _HashableMap(cls_map), tile,
                    (w.shape[0], w.shape[1]), fset)
 
@@ -156,10 +175,7 @@ class MPMatrix:
     def padded_dense(self) -> jax.Array:
         """Padded dense fp32 view with per-tile storage rounding applied
         (each tile is valid in exactly one buffer, the rest are zeros)."""
-        d = self.bufs[0].astype(jnp.float32)
-        for b in self.bufs[1:]:
-            d = d + b.astype(jnp.float32)
-        return d
+        return dense_of(self.bufs)
 
     def to_dense(self) -> jax.Array:
         """Materialize at fp32 with storage-precision rounding applied

@@ -18,13 +18,19 @@ parallel dtype table here.
 """
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
 from repro.core.formats import DEFAULT_FORMATS, FormatSet, SplitFormat
-from repro.core.layout import MPMatrix
+from repro.core.layout import MPMatrix, _pad_to, dense_of, tile_buffers
+
+#: traces of the ``ref`` GEMM's device program, labelled by the C classes
+#: present (``classes=bf16+fp32``); steady-state calls add none
+REF_TRACES_METRIC = "gemm.ref_traces"
 
 
 def _class_dot(ad: jax.Array, bd: jax.Array, fmt) -> jax.Array:
@@ -34,49 +40,57 @@ def _class_dot(ad: jax.Array, bd: jax.Array, fmt) -> jax.Array:
         from repro.split.recovery import split_dot_general
         return split_dot_general(ad, bd, fmt)
     op = fmt.compute_dtype
-    return jax.lax.dot_general(
-        ad.astype(op), bd.astype(op), (((1,), (0,)), ((), ())),
+    # materialize the dot's operands and result: inside a jitted caller
+    # XLA fuses the operands' producers (buffer sums, converts) into the
+    # dot, rerunning them at every operand-tile load, and the select and
+    # store into its output; a 16384³ bf16 dot on a TPU v5e then took 181
+    # ms (64 ms with the producers alone kept out) against 47 ms alone
+    ad, bd = jax.lax.optimization_barrier((ad.astype(op), bd.astype(op)))
+    return jax.lax.optimization_barrier(jax.lax.dot_general(
+        ad, bd, (((1,), (0,)), ((), ())),
         precision=fmt.dot_precision,
-        preferred_element_type=jnp.float32)
+        preferred_element_type=jnp.float32))
 
 
-def _storage_dense(m: MPMatrix) -> jax.Array:
-    """Padded dense fp32 view with per-tile storage rounding applied."""
-    return m.padded_dense()
-
-
-def _expand(cls_map: np.ndarray, tile: int) -> np.ndarray:
-    return np.repeat(np.repeat(cls_map, tile, 0), tile, 1)
+@partial(jax.jit, static_argnames=("classes", "tile", "shape", "fset",
+                                   "alpha", "beta"))
+def _ref_program(a_bufs, b_bufs, c_bufs, c_map, *, classes, tile, shape,
+                 fset, alpha, beta):
+    """The device program of :func:`mp_gemm_ref`.  ``c_map`` is C's int8
+    ``[mt, nt]`` class map as a traced argument, so a new map with the same
+    ``classes`` present reuses the compiled program."""
+    # Python here runs once per trace, not per call
+    obs.metrics_registry().counter(
+        REF_TRACES_METRIC,
+        classes="+".join(fset.fmt(cc).name for cc in classes)).inc()
+    ad, bd, cd = dense_of(a_bufs), dense_of(b_bufs), dense_of(c_bufs)
+    mt, nt = c_map.shape
+    sel = c_map[:, None, :, None]
+    out = jnp.zeros((mt, tile, nt, tile), jnp.float32)
+    for cc in classes:
+        upd = alpha * _class_dot(ad, bd, fset.fmt(cc)) + beta * cd
+        out = jnp.where(sel == cc, upd.reshape(out.shape), out)
+    out = out.reshape(cd.shape)
+    # store back in C's per-tile precision
+    wp = _pad_to(out[: shape[0], : shape[1]], *cd.shape)
+    return tile_buffers(wp, c_map, tile, fset)
 
 
 def mp_gemm_ref(a: MPMatrix, b: MPMatrix, c: MPMatrix,
                 alpha: float = 1.0, beta: float = 0.0) -> MPMatrix:
-    """Oracle implementation: one dense dot per C-precision class present,
-    then a per-tile select.  Numerically exact w.r.t. the tile semantics;
-    not the performance path (classes × MNK flops)."""
-    with obs.span("gemm.storage_view", "gemm"):
-        ad, bd = _storage_dense(a), _storage_dense(b)
-        cd = _storage_dense(c)
-    fset = c.fset
-    classes = sorted({int(v) for v in np.unique(c.cls.arr)})
-    per_class = {}
-    for cc in classes:
-        fmt = fset.fmt(cc)
-        with obs.span("gemm.class_dot", "gemm", format=fmt.name):
-            acc = _class_dot(ad, bd, fmt)
-            per_class[cc] = alpha * acc + beta * cd
-    with obs.span("gemm.expand_map", "gemm"):
-        host_sel = _expand(c.cls.arr, c.tile)
-    with obs.span("gemm.upload_map", "gemm"):
-        sel = jnp.asarray(host_sel)
-    with obs.span("gemm.select", "gemm"):
-        out = jnp.zeros_like(cd)
-        for cc in classes:
-            out = jnp.where(sel == cc, per_class[cc], out)
-    # store back in C's per-tile precision
-    with obs.span("gemm.store", "gemm"):
-        return MPMatrix.from_dense(
-            out[: c.shape[0], : c.shape[1]], c.cls.arr, c.tile, fset)
+    """One dense dot per C-precision class present, then a per-tile
+    select: numerically exact w.r.t. the tile semantics, and the path the
+    cost model picks for the benchmark's 16384³ GEMMs on a TPU v5e.
+
+    The host reads which classes C's map holds; everything else runs as
+    one compiled program (:func:`_ref_program`), the map expanded on the
+    device.  ``gemm.ref_traces{classes=…}`` counts its traces."""
+    classes = tuple(int(v) for v in np.unique(c.cls.arr))
+    bufs = _ref_program(
+        tuple(a.bufs), tuple(b.bufs), tuple(c.bufs),
+        jnp.asarray(c.cls.arr, jnp.int8), classes=classes, tile=c.tile,
+        shape=c.shape, fset=c.fset, alpha=alpha, beta=beta)
+    return MPMatrix(bufs, c.cls, c.tile, c.shape, c.fset)
 
 
 def mp_gemm_tilewise_ref(a: MPMatrix, b: MPMatrix, c: MPMatrix,
@@ -85,8 +99,7 @@ def mp_gemm_tilewise_ref(a: MPMatrix, b: MPMatrix, c: MPMatrix,
     to validate mp_gemm_ref itself in tests.  Returns dense fp32."""
     t = c.tile
     fset = c.fset
-    ad, bd, cd = map(np.asarray, (_storage_dense(a), _storage_dense(b),
-                                  _storage_dense(c)))
+    ad, bd, cd = (np.asarray(m.padded_dense()) for m in (a, b, c))
     mt, kt = a.cls.arr.shape
     kt2, nt = b.cls.arr.shape
     assert kt == kt2

@@ -4,7 +4,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core import MPMatrix, make_map, mp_gemm_ref, mp_gemm_tilewise_ref
+from repro.core.formats import DEFAULT_FORMATS, format_set
+from repro.core.mp_gemm import REF_TRACES_METRIC
 from repro.core.precision import PAPER_RATIOS, Policy
 
 
@@ -85,3 +88,66 @@ def test_accuracy_monotone_in_high_ratio():
         out = np.asarray(mp_gemm_ref(A, B, C).to_dense(), np.float64)
         errs.append(np.abs(out - exact).mean())   # mean: max saturates at
     assert errs[2] < errs[1] < errs[0]             # the bf16 output rounding
+
+
+def _class_operands(fset, M, K, N, t, seed):
+    """A, B, C in ``fset`` with every class of the set drawn per tile."""
+    rng = np.random.default_rng(seed)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    mats = []
+    for key, (r, c) in zip(keys, ((M, K), (K, N), (M, N))):
+        cls = rng.integers(0, len(fset), size=(-(-r // t), -(-c // t)))
+        mats.append(MPMatrix.from_dense(jax.random.normal(key, (r, c)),
+                                        cls.astype(np.int8), t, fset))
+    return mats
+
+
+@pytest.mark.parametrize("alpha,beta", [(1.0, 0.0), (0.5, 2.0)])
+@pytest.mark.parametrize("names", [DEFAULT_FORMATS.names,
+                                   ("fp16", "split2_fp16"),
+                                   ("int8_pt", "bf16")],
+                         ids=lambda n: "+".join(n))
+def test_compiled_ref_equals_its_eager_body(names, alpha, beta):
+    """The one-program ``ref`` GEMM is bit for bit its own body run op by
+    op, in every output buffer (padded edges included)."""
+    fset = format_set(*names)
+    A, B, C = _class_operands(fset, 40, 56, 24, 16, seed=len(names))
+    got = mp_gemm_ref(A, B, C, alpha=alpha, beta=beta)
+    with jax.disable_jit():
+        want = mp_gemm_ref(A, B, C, alpha=alpha, beta=beta)
+    assert len(got.bufs) == len(want.bufs) == len(fset)
+    for g, w in zip(got.bufs, want.bufs):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g.astype(jnp.float32)),
+                                      np.asarray(w.astype(jnp.float32)))
+
+
+def test_ref_traces_once_per_class_set():
+    """The class map is a traced argument: repeated calls and a new map
+    with the same classes present reuse one program; a new class set
+    traces again."""
+    t = 8
+    M = K = N = 3 * t              # a shape no other test here compiles
+    a = jax.random.normal(jax.random.PRNGKey(5), (M, K))
+    A = MPMatrix.from_dense(a, np.array([[0, 1, 2]] * 3, np.int8), t)
+    B = MPMatrix.from_dense(a.T, np.array([[2, 1, 0]] * 3, np.int8), t)
+    zero = jnp.zeros((M, N))
+
+    def traces():
+        return sum(s.value for _, s in
+                   obs.metrics_registry().series(REF_TRACES_METRIC))
+
+    def call(cls_map):
+        C = MPMatrix.from_dense(zero, np.asarray(cls_map, np.int8), t)
+        jax.block_until_ready(mp_gemm_ref(A, B, C).bufs)
+
+    start = traces()
+    for _ in range(3):
+        call([[1, 2, 1], [2, 1, 2], [1, 1, 1]])
+    assert traces() == start + 1
+    call([[2, 2, 2], [1, 1, 1], [2, 1, 1]])     # same classes, new map
+    assert traces() == start + 1
+    call([[0, 2, 2], [1, 1, 1], [2, 1, 1]])     # fp8 tile: a new class set
+    assert traces() == start + 2
+    assert obs.metrics_registry().value(
+        REF_TRACES_METRIC, classes="fp8_e4m3+bf16+fp32") >= 1

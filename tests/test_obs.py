@@ -207,8 +207,9 @@ def _nested(spans, child: str, parent: str) -> bool:
 
 
 def test_ref_gemm_spans_reach_the_profiler(tmp_path):
-    """With JSONL tracing off, a ``ref`` GEMM's host phases appear on the
-    profiler's timeline, the map expansion and upload inside dispatch."""
+    """With JSONL tracing off, a ``ref`` GEMM's resolve and dispatch spans
+    appear on the profiler's timeline; its work is one device program, so
+    no host phase (map expansion or upload) is spanned under dispatch."""
     from repro.tune import dispatch as TD
     from repro.tune.costmodel import GemmPlan
     A, C = _tiny_mp_operands()
@@ -218,14 +219,11 @@ def test_ref_gemm_spans_reach_the_profiler(tmp_path):
             TD.mp_matmul(A, A, C, plan=GemmPlan("ref")).bufs),
         tmp_path)
     names = {n for _, _, n in spans}
-    assert {"repro.gemm.resolve", "repro.gemm.storage_view",
-            "repro.gemm.class_dot", "repro.gemm.select",
-            "repro.gemm.store"} <= names
-    for child in ("repro.gemm.expand_map", "repro.gemm.upload_map",
-                  "repro.gemm.class_dot"):
-        assert _nested(spans, child, "repro.gemm.dispatch"), child
-    # one class-dot span per output class present (the map is 50/50)
-    assert sum(n == "repro.gemm.class_dot" for _, _, n in spans) == 2
+    assert {"repro.gemm.resolve", "repro.gemm.dispatch"} <= names
+    gemm = {n for n in names if n.startswith("repro.gemm.")}
+    assert gemm == {"repro.gemm.resolve", "repro.gemm.dispatch"}, gemm
+    for gone in ("repro.gemm.expand_map", "repro.gemm.upload_map"):
+        assert not _nested(spans, gone, "repro.gemm.dispatch"), gone
 
 
 def test_serve_refill_and_drain_nest_in_the_retirement_pass(tmp_path):
