@@ -254,7 +254,14 @@ def decode_attention(params, x, dims: AttnDims, cache_k, cache_v, *,
     generated suffix, never another request's padding).  ``slot`` itself
     may be a [B] vector — the continuous-decode engine's retire-and-refill
     slots progress independently per row, so each row scatters its new KV
-    into its own cache position."""
+    into its own cache position.
+
+    GQA groups are contracted against the cache as stored: q (scaled, f32)
+    is split into [B, 1, n_kv, group, dh], query head ``k·group + g``
+    attending KV head ``k``, so no copy of the cache repeated to every
+    query head is made.  Scores and ``p·V`` are f32 contractions at
+    ``Precision.HIGHEST`` against the cache's values upcast (the cache
+    itself stays in its dtype); the softmax is f32."""
     B = x.shape[0]
     nq, nkv, dh = dims.n_q, dims.n_kv, dims.head_dim
     S_max = cache_k.shape[1]
@@ -281,11 +288,11 @@ def decode_attention(params, x, dims: AttnDims, cache_k, cache_v, *,
             cache_k, k.astype(cache_k.dtype), slot, axis=1)
         cache_v = jax.lax.dynamic_update_slice_in_dim(
             cache_v, v.astype(cache_v.dtype), slot, axis=1)
-    kk = _repeat_kv(cache_k, dims.group)      # [B, S_max, nq, dh]
-    vv = _repeat_kv(cache_v, dims.group)
     scale = 1.0 / np.sqrt(dh)
-    s = jnp.einsum("bqhd,bshd->bhqs", q.astype(jnp.float32) * scale,
-                   kk.astype(jnp.float32))
+    qg = (q.astype(jnp.float32) * scale).reshape(B, 1, nkv, dims.group, dh)
+    hi = jax.lax.Precision.HIGHEST
+    s = jnp.einsum("bqkgd,bskd->bkgqs", qg, cache_k.astype(jnp.float32),
+                   precision=hi)
     kv_pos = jnp.arange(S_max)
     if kv_valid is not None:
         valid = kv_valid
@@ -297,9 +304,10 @@ def decode_attention(params, x, dims: AttnDims, cache_k, cache_v, *,
         valid = kv_pos[None, :] <= position
     if valid.ndim == 1:
         valid = valid[None]
-    s = jnp.where(valid[:, None, None, :], s, -1e30)
+    s = jnp.where(valid[:, None, None, None, :], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhqs,bshd->bqhd", p, vv.astype(jnp.float32))
+    out = jnp.einsum("bkgqs,bskd->bqkgd", p, cache_v.astype(jnp.float32),
+                     precision=hi)
     out = out.reshape(B, 1, nq * dh).astype(ACT_DTYPE)
     return params["wo"](out).astype(ACT_DTYPE), cache_k, cache_v
 

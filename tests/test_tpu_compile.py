@@ -3,13 +3,18 @@
 Interpret mode (every other test) cannot see what Mosaic refuses, so these
 tests lower each kernel at real widths for a *described* v5e chip — the
 TPU compiler is installed even where no chip is attached — and assert that
-the compiled program holds the kernel (``tpu_custom_call``).  Nothing runs;
-a passing compile says nothing about results or times.
+the compiled program holds the kernel (``tpu_custom_call``).  Decode
+attention is compiled the same way, and its optimised HLO read for what
+the serve steps must not do.  Nothing runs; a passing compile says nothing
+about results or times.
 
 The topology is described inside a module-scoped fixture, never at import
 time: only one process may load the TPU library, and pytest-xdist workers
 import every test module.
 """
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -25,6 +30,7 @@ from repro.kernels.grouped_gemm import _grouped_class_call
 from repro.kernels.ksplit_gemm import ksplit_gemm_multi
 from repro.kernels.mp_gemm_tile import format_specs, mp_gemm_tile_multi
 from repro.kernels.split_gemm import split_gemm_tile_multi
+from repro.models import common as MC
 from repro.split.recovery import split_format_specs
 from repro.tune import dispatch as TD
 from repro.tune import search as TS
@@ -153,6 +159,57 @@ def test_convert_kernel_compiles(one_chip):
     x = _shape(one_chip, (N, N), jnp.float32)
     _assert_kernel(convert.lower(x, out_dtype=jnp.bfloat16,
                                  interpret=False))
+
+
+_HLO_INSTR = re.compile(
+    r"^\s*(?:ROOT )?%(?P<name>[\w.\-]+) = (?P<dtype>\w+)\[(?P<dims>[\d,]*)\]"
+    r"\S* (?P<op>[\w\-]+)\((?P<args>[^)]*)\)(?P<attrs>.*)$", re.M)
+
+
+@pytest.mark.parametrize("caller", ["decode_cont", "prefill_scan"])
+def test_decode_attention_reads_the_gqa_cache_in_place(one_chip, caller):
+    """At the InternLM2-1.8B backlog cell's shapes, neither the masked
+    decode step (per-row slot and ``kv_valid``) nor the prefill scan's step
+    (scalar position) materialises a copy of the cache repeated to every
+    query head, and both contractions against the bf16 cache take their
+    other operand (scaled q, probabilities) in f32 at HIGHEST."""
+    B, S, nq, nkv, dh, d = 16, 512, 16, 8, 128, 2048
+    dims = MC.AttnDims(nq, nkv, dh, nq, nkv)
+    bf16 = lambda *shape: _shape(one_chip, shape, jnp.bfloat16)
+    weights = {"wq": bf16(d, nq * dh), "wk": bf16(d, nkv * dh),
+               "wv": bf16(d, nkv * dh), "wo": bf16(nq * dh, d)}
+    cache = bf16(B, S, nkv, dh)
+    if caller == "decode_cont":
+        rows = _shape(one_chip, (B,), jnp.int32)
+        step = dict(position=rows, slot=rows,
+                    kv_valid=_shape(one_chip, (B, S), jnp.bool_))
+    else:
+        step = dict(position=_shape(one_chip, (), jnp.int32))
+
+    def attend(w, x, ck, cv, step):
+        params = {k: (lambda t, m=m: t @ m) for k, m in w.items()}
+        return MC.decode_attention(params, x, dims, ck, cv, rope_theta=1e6,
+                                   **step)
+
+    hlo = jax.jit(attend).lower(weights, bf16(B, 1, d), cache, cache,
+                                step).compile().as_text()
+    instrs = list(_HLO_INSTR.finditer(hlo))
+    shapes = {m["name"]: (m["dtype"], [int(n) for n in m["dims"].split(",")
+                                       if n]) for m in instrs}
+    repeated = [m["name"] for m in instrs
+                if math.prod(shapes[m["name"]][1]) == B * S * nq * dh]
+    assert not repeated, repeated
+    cache_elems = B * S * nkv * dh
+    against_cache = []
+    for m in instrs:
+        if m["op"] not in ("dot", "convolution"):
+            continue
+        ops = [shapes[a.strip().lstrip("%")] for a in m["args"].split(",")]
+        if any(math.prod(dims) == cache_elems for _, dims in ops):
+            against_cache.append(m)
+            assert sorted(dt for dt, _ in ops) == ["bf16", "f32"], ops
+            assert "operand_precision={highest,highest}" in m["attrs"]
+    assert len(against_cache) == 2, [m["name"] for m in against_cache]
 
 
 @pytest.mark.parametrize("path,fs", [
