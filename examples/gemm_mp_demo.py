@@ -9,7 +9,6 @@ os.environ.setdefault("XLA_FLAGS",
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.core import MPMatrix, mp_gemm_ref, schedule
 from repro.core.precision import PAPER_RATIOS

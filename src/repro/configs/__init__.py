@@ -1,2 +1,2 @@
-from repro.configs.base import (REGISTRY, SHAPES, ArchConfig, cells, get,
-                                load_all, reduced, register)
+from repro.configs.base import (REGISTRY, ArchConfig, get, load_all, reduced,
+                                register)

@@ -14,7 +14,8 @@ from repro.core.precision import Policy
 
 REGISTRY: dict[str, "ArchConfig"] = {}
 
-#: model-parallel axis size of the production mesh (16×16 pod)
+#: default model-parallel width (sized for a 16×16 pod; the serve
+#: launcher overrides it to 1)
 DEFAULT_TP = 16
 
 
@@ -192,21 +193,6 @@ def get(name: str) -> ArchConfig:
     return REGISTRY[name]
 
 
-# ---------------------------------------------------------------------------
-# Input shape sets (assigned): seq_len × global_batch
-# ---------------------------------------------------------------------------
-
-SHAPES = {
-    "train_4k": dict(seq_len=4096, global_batch=256, kind="train"),
-    "prefill_32k": dict(seq_len=32768, global_batch=32, kind="prefill"),
-    "decode_32k": dict(seq_len=32768, global_batch=128, kind="decode"),
-    "long_500k": dict(seq_len=524288, global_batch=1, kind="decode"),
-}
-
-#: archs allowed to run long_500k (sub-quadratic path exists)
-LONG_OK = {"jamba-v0.1-52b", "gemma3-4b", "xlstm-1.3b"}
-
-
 def reduced(cfg: ArchConfig, tp: int = 2) -> ArchConfig:
     """Tiny same-family variant for CPU smoke tests: keeps the block
     pattern/family structure, shrinks every dimension."""
@@ -234,14 +220,3 @@ def reduced(cfg: ArchConfig, tp: int = 2) -> ArchConfig:
         serve_buckets=(4, 8, 16, 32),
     )
     return dataclasses.replace(cfg, **kw)
-
-
-def cells(arch: str) -> list[str]:
-    """Dry-run cells for an arch, applying the documented skips."""
-    cfg = get(arch)
-    out = ["train_4k", "prefill_32k"]
-    if not cfg.encoder_only:
-        out.append("decode_32k")
-        if arch in LONG_OK:
-            out.append("long_500k")
-    return out

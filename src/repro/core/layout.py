@@ -25,7 +25,6 @@ lives in numpy/aux on the host and is hashed into jit keys.
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
 
 import jax
 import jax.numpy as jnp

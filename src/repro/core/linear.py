@@ -23,10 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import precision as P
 from repro.core.formats import DEFAULT_FORMATS, FormatSet
-from repro.core.layout import (KSplitWeight, NSplitWeight, ksplit_matmul,
-                               nsplit_matmul)
+from repro.core.layout import KSplitWeight, NSplitWeight, nsplit_matmul
 from repro.core.precision import Policy, role_class_vector
 
 _TILE_PREFS = (128, 64, 32, 16, 8, 4, 2, 1)

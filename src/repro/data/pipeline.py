@@ -20,8 +20,8 @@ from repro.configs.base import ArchConfig
 
 def batch_spec(cfg: ArchConfig, seq_len: int, global_batch: int,
                kind: str) -> dict:
-    """ShapeDtypeStruct stand-ins for every model input (dry-run uses these
-    directly; the pipeline materializes matching arrays)."""
+    """ShapeDtypeStruct stand-ins for every model input (the pipeline
+    materializes matching arrays)."""
     B, S = global_batch, seq_len
     f32, i32 = jnp.float32, jnp.int32
     if kind in ("train", "prefill"):

@@ -10,7 +10,6 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 

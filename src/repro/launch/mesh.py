@@ -1,11 +1,7 @@
-"""Production meshes.
-
-Single pod: 16×16 = 256 chips, axes ("data", "model").
-Multi-pod:  2×16×16 = 512 chips, axes ("pod", "data", "model") — "pod" is a
-second (hierarchical) data-parallel axis crossing the inter-pod links.
+"""Device meshes: the P×Q grid of distributed SUMMA and a small host mesh.
 
 Functions, not module constants: importing this module never touches jax
-device state (the dry-run sets XLA_FLAGS before first jax init).
+device state (a launcher sets XLA_FLAGS before first jax init).
 """
 from __future__ import annotations
 
@@ -24,13 +20,6 @@ def _require_devices(need: int, what: str) -> None:
             f"initializes (or pass --devices {need} to the launcher)")
 
 
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    _require_devices(512 if multi_pod else 256, "make_production_mesh")
-    return jax.make_mesh(shape, axes)
-
-
 def make_host_mesh(data: int = 2, model: int = 2):
     """Small mesh over host CPU devices (tests)."""
     _require_devices(data * model, f"make_host_mesh({data}x{model})")
@@ -42,13 +31,3 @@ def make_grid_mesh(rows: int = 2, cols: int = 2,
     """P×Q device grid for distributed SUMMA (core.summa)."""
     _require_devices(rows * cols, f"make_grid_mesh({rows}x{cols})")
     return jax.make_mesh((rows, cols), tuple(axes))
-
-
-def data_axes(mesh) -> tuple[str, ...]:
-    """All batch-parallel axes present in the mesh."""
-    names = mesh.axis_names
-    return tuple(a for a in ("pod", "data") if a in names)
-
-
-def model_axis_size(mesh) -> int:
-    return mesh.shape["model"]

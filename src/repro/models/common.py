@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.formats import DEFAULT_FORMATS, FormatSet
-from repro.core.linear import MPLinear, init_mp_linear
+from repro.core.linear import init_mp_linear
 from repro.core.precision import Policy
 
 ACT_DTYPE = jnp.bfloat16
@@ -172,13 +172,9 @@ def flash_attention(q, k, v, *, causal: bool, kv_chunk: int = 1024,
             "bhqk,bhkd->bhqd", p, vb.astype(jnp.float32))
         return (m_new, l_new, acc_new), None
 
-    from repro.models.shard_hints import hint
-    m0 = hint(jnp.full((B, H, Sq), -1e30, jnp.float32),
-              ("pod", "data"), "model", None)
-    l0 = hint(jnp.zeros((B, H, Sq), jnp.float32),
-              ("pod", "data"), "model", None)
-    a0 = hint(jnp.zeros((B, H, Sq, dh), jnp.float32),
-              ("pod", "data"), "model", None, None)
+    m0 = jnp.full((B, H, Sq), -1e30, jnp.float32)
+    l0 = jnp.zeros((B, H, Sq), jnp.float32)
+    a0 = jnp.zeros((B, H, Sq, dh), jnp.float32)
     (m, l, acc), _ = jax.lax.scan(
         step, (m0, l0, a0),
         (jnp.arange(nchunks), kc.transpose(2, 0, 1, 3, 4),
@@ -196,13 +192,11 @@ def sliding_window_attention(q, k, v, *, window: int) -> jax.Array:
     if S <= w:
         return flash_attention(q, k, v, causal=True, kv_chunk=min(1024, S))
     assert S % w == 0, (S, w)
-    from repro.models.shard_hints import hint
     nb = S // w
     scale = 1.0 / np.sqrt(dh)
-    bh = lambda t: hint(t, ("pod", "data"), "model", None, None, None)
-    qb = bh(q.reshape(B, H, nb, w, dh).astype(jnp.float32) * scale)
-    kb = bh(k.reshape(B, H, nb, w, dh))
-    vb = bh(v.reshape(B, H, nb, w, dh))
+    qb = q.reshape(B, H, nb, w, dh).astype(jnp.float32) * scale
+    kb = k.reshape(B, H, nb, w, dh)
+    vb = v.reshape(B, H, nb, w, dh)
     k_prev = jnp.concatenate([jnp.zeros_like(kb[:, :, :1]), kb[:, :, :-1]], 2)
     v_prev = jnp.concatenate([jnp.zeros_like(vb[:, :, :1]), vb[:, :, :-1]], 2)
     k_band = jnp.concatenate([k_prev, kb], 3)   # [B,H,nb,2w,dh]
@@ -224,12 +218,11 @@ def attention_block(params, x, dims: AttnDims, *, positions, causal=True,
                     window: int | None = None, rope_theta=10000.0,
                     use_rope=True) -> jax.Array:
     """Full training/prefill attention.  x: [B, S, d]."""
-    from repro.models.shard_hints import heads_hint
     B, S, _ = x.shape
     q, k, v = _qkv(params, x, dims, positions, rope_theta, use_rope)
-    q = heads_hint(q.transpose(0, 2, 1, 3))
-    k = heads_hint(_repeat_kv(k, dims.group).transpose(0, 2, 1, 3))
-    v = heads_hint(_repeat_kv(v, dims.group).transpose(0, 2, 1, 3))
+    q = q.transpose(0, 2, 1, 3)
+    k = _repeat_kv(k, dims.group).transpose(0, 2, 1, 3)
+    v = _repeat_kv(v, dims.group).transpose(0, 2, 1, 3)
     if window is not None and causal:
         out = sliding_window_attention(q, k, v, window=window)
     else:

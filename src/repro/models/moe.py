@@ -1,9 +1,9 @@
 """Mixture-of-Experts FFN with tile-centric mixed-precision experts.
 
 Dispatch: top-k token-choice routing with a fixed per-expert capacity and
-gather/scatter index dispatch (no [T, E, C] one-hot tensors).  Expert
-parallelism shards the E dim over "model" when E % axis == 0; otherwise
-experts are replicated and each expert's d_ff is TP-sharded.
+gather/scatter index dispatch (no [T, E, C] one-hot tensors).
+``init_moe(ep=...)`` lays the down projection out for expert parallelism
+(per-expert K-split) or for a d_ff split (N-split).
 
 Mixed precision at two granularities (DESIGN.md §5/§6):
   * per-expert K-split — every expert's weight carries the same K-class
@@ -158,162 +158,6 @@ def init_moe(key, d_model: int, d_ff: int, n_experts: int, top_k: int,
                                     shared_d_ff or d_ff * n_shared, policy,
                                     tile)
     return params
-
-
-def _dispatch_tables(xf, router, top_k: int, capacity_factor: float):
-    """Shared routing math: returns (table [E,C] token ids with sentinel T,
-    gate_table [E,C], probs, flat_e, keep)."""
-    T, d = xf.shape
-    E = router.shape[1]
-    logits = xf.astype(jnp.float32) @ router
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, expert_ids = jax.lax.top_k(probs, top_k)
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(-1, keepdims=True), 1e-9)
-    C = max(int(np.ceil(T * top_k / E * capacity_factor)), 1)
-    flat_e = expert_ids.reshape(-1)
-    onehot = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)
-    pos_in_e = jnp.cumsum(onehot, axis=0) - onehot
-    my_pos = jnp.take_along_axis(pos_in_e, flat_e[:, None], 1)[:, 0]
-    keep = my_pos < C
-    tok_idx = jnp.repeat(jnp.arange(T), top_k)
-    table = jnp.full((E, C), T, jnp.int32)
-    table = table.at[flat_e, jnp.where(keep, my_pos, C)].set(
-        tok_idx, mode="drop")
-    gate_table = jnp.zeros((E, C), jnp.float32)
-    gate_table = gate_table.at[flat_e, jnp.where(keep, my_pos, C)].add(
-        gate_vals.reshape(-1).astype(jnp.float32), mode="drop")
-    return table, gate_table, probs, flat_e, keep, C
-
-
-def moe_block_sharded(params, x, *, top_k: int, mesh, ep: bool,
-                      capacity_factor: float = 1.25):
-    """Explicit shard_map MoE — the collective-efficient production path.
-
-    The pjit auto-sharded gather dispatch triggers "involuntary full
-    rematerialization" in the SPMD partitioner (expert compute replicated
-    over 'model', ~9× FLOPs and TB-scale all-reduces on qwen2 — see
-    EXPERIMENTS.md §Perf iteration B).  Here the dataflow is explicit:
-
-      * routing + dispatch tables are computed per data shard (tokens are
-        data-sharded, x is replicated over 'model');
-      * EP (E % tp == 0): every model shard gathers the [E, C, d] buckets
-        locally (no communication — x is replicated over 'model') and
-        computes only its own E/tp experts;
-      * non-EP: every model shard computes all experts over its d_ff slice;
-      * one bf16 psum over 'model' combines expert partial outputs.
-
-    Returns (y [B,S,d], aux scalar).  Capacity is per-data-shard.
-    """
-    from jax import shard_map
-    from jax.sharding import PartitionSpec as P
-
-    B, S, d = x.shape
-    E = params["router"].shape[1]
-    axes = mesh.axis_names
-    data_axes = tuple(a for a in ("pod", "data") if a in axes)
-    tp = mesh.shape["model"]
-
-    gate, up, down = params["gate"], params["up"], params["down"]
-    if ep:
-        wspec = P("model", None, None)
-        dspec = P("model", None, None)
-    else:
-        wspec = P(None, None, "model")          # gate/up: d_ff columns
-        dspec = P(None, "model", None)          # down: d_ff rows
-    dp = 1
-    for a in data_axes:
-        dp *= mesh.shape[a]
-    if B % dp:                        # e.g. batch-1 long-context decode
-        data_axes = ()
-    da = (data_axes if len(data_axes) > 1 else
-          (data_axes[0] if data_axes else None))
-    # sequence-shard the activation over 'model' when S divides: the
-    # boundary collectives become bf16 all-gather (in) / reduce-scatter
-    # (out) and — critically — the backward cotangent of x is sharded
-    # instead of an fp32 psum_invariant over 'model' (61 % of qwen2's
-    # collective bytes before this change; EXPERIMENTS §Perf B3).
-    seq_shard = S % tp == 0
-    xspec = P(da, "model" if seq_shard else None, None)
-
-    def local_fn(x_loc, router, g_hi, g_lo, u_hi, u_lo, d_hi, d_lo):
-        if seq_shard:
-            x_loc = jax.lax.all_gather(x_loc.astype(ACT_DTYPE), "model",
-                                       axis=1, tiled=True)
-        Bl, Sl, _ = x_loc.shape
-        xf = x_loc.reshape(Bl * Sl, d)
-        T = Bl * Sl
-        table, gate_table, probs, flat_e, keep, C = _dispatch_tables(
-            xf, router, top_k, capacity_factor)
-        xpad = jnp.concatenate([xf, jnp.zeros((1, d), xf.dtype)], 0)
-        xe = jnp.take(xpad, table.reshape(-1), axis=0).reshape(E, C, d)
-        if ep:
-            e_loc = E // tp
-            midx = jax.lax.axis_index("model")
-            xe = jax.lax.dynamic_slice_in_dim(xe, midx * e_loc, e_loc, 0)
-            gt = jax.lax.dynamic_slice_in_dim(gate_table, midx * e_loc,
-                                              e_loc, 0)
-            tbl = jax.lax.dynamic_slice_in_dim(table, midx * e_loc,
-                                               e_loc, 0)
-        else:
-            gt, tbl = gate_table, table
-
-        def mm(xin, hi, lo, prec_k_split=True):
-            # per-class batched expert matmul (receiver-side conversion)
-            parts = []
-            if hi.shape[1 if prec_k_split else 2]:
-                k_hi = hi.shape[1] if prec_k_split else None
-                a = (xin[..., :hi.shape[1]] if prec_k_split else xin)
-                parts.append(jnp.einsum(
-                    "eck,ekn->ecn", a.astype(jnp.float32), hi,
-                    precision=jax.lax.Precision.HIGHEST,
-                    preferred_element_type=jnp.float32))
-            if lo.shape[1 if prec_k_split else 2]:
-                a = (xin[..., hi.shape[1]:] if prec_k_split else xin)
-                parts.append(jnp.einsum(
-                    "eck,ekn->ecn", a.astype(jnp.bfloat16), lo,
-                    preferred_element_type=jnp.float32))
-            if len(parts) == 1:
-                return parts[0]
-            if prec_k_split:
-                return parts[0] + parts[1]
-            return jnp.concatenate(parts, -1)
-
-        h = jax.nn.silu(mm(xe, g_hi, g_lo)) * mm(xe, u_hi, u_lo)
-        h = h.astype(ACT_DTYPE)
-        down_is_ksplit = ep
-        ye = mm(h, d_hi, d_lo, prec_k_split=down_is_ksplit)
-        weighted = (ye * gt[..., None]).astype(jnp.float32)
-        out = jnp.zeros((T + 1, d), jnp.float32)
-        out = out.at[tbl.reshape(-1)].add(
-            weighted.reshape(-1, d), mode="drop")[:T]
-        if seq_shard:
-            out = out.reshape(Bl, Sl, d)
-            out = jax.lax.psum_scatter(out.astype(jnp.bfloat16), "model",
-                                       scatter_dimension=1, tiled=True)
-            out = out.reshape(-1, d)
-        else:
-            out = jax.lax.psum(out.astype(jnp.bfloat16), "model")
-        # load-balance aux (identical on every model shard)
-        me = probs.mean(0)
-        ce = jnp.zeros((E,), jnp.float32).at[flat_e].add(
-            jnp.where(keep, 1.0, 0.0)) / max(T * top_k, 1)
-        aux = E * jnp.sum(me * ce)
-        for a in data_axes + ("model",):   # model-pmean: no-op numerically
-            aux = jax.lax.pmean(aux, a)    # (satisfies vma replication)
-        return out.reshape(Bl, -1, d), aux
-
-    y, aux = shard_map(
-        local_fn, mesh=mesh,
-        in_specs=(xspec, P(), wspec, wspec, wspec, wspec, dspec, dspec),
-        out_specs=(xspec, P()),
-    )(x, params["router"], gate.w_hi, gate.w_lo, up.w_hi, up.w_lo,
-      down.w_hi, down.w_lo)
-    if "shared" in params:
-        from repro.models.common import mlp_block
-        y = (y.astype(jnp.float32)
-             + mlp_block(params["shared"], x).astype(jnp.float32))
-    return y.astype(ACT_DTYPE), aux
 
 
 def moe_block(params, x, *, top_k: int, capacity_factor: float = 1.25,

@@ -14,11 +14,10 @@ mixed-precision GEMM is the matmul substrate of every architecture.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Optional
+from typing import Any
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.core.formats import FormatSet
@@ -115,16 +114,9 @@ def _apply_layer(params, x, cfg: ArchConfig, mixer: str, ffn: str, *,
         if ffn == "mlp":
             x = x + C.mlp_block(params[ffn], h2)
         else:
-            from repro.models.shard_hints import active_mesh
-            mesh = active_mesh()
-            if mesh is not None and "model" in mesh.axis_names:
-                out, aux = MOE.moe_block_sharded(
-                    params["moe"], h2, top_k=cfg.top_k, mesh=mesh,
-                    ep=cfg.moe_ep, capacity_factor=cfg.capacity_factor)
-            else:
-                out, aux = MOE.moe_block(
-                    params["moe"], h2, top_k=cfg.top_k,
-                    capacity_factor=cfg.capacity_factor, return_aux=True)
+            out, aux = MOE.moe_block(
+                params["moe"], h2, top_k=cfg.top_k,
+                capacity_factor=cfg.capacity_factor, return_aux=True)
             x = x + out
     return x.astype(ACT_DTYPE), aux, new_cache
 
@@ -271,8 +263,6 @@ def _run_segments(params, cfg: ArchConfig, x, positions, remat: bool):
         seg_params = params["blocks"][seg_idx]
 
         def body(x, layer_params, pattern=pattern):
-            from repro.models.shard_hints import constrain_layer_params
-            layer_params = constrain_layer_params(layer_params, cfg)
             aux_sum = jnp.zeros((), jnp.float32)
             for pi, (mixer, ffn) in enumerate(pattern):
                 x, aux, _ = _apply_layer(layer_params[f"pos{pi}"], x, cfg,
@@ -338,7 +328,7 @@ def forward_prefill(params, cfg: ArchConfig, batch: dict):
     """Prefill: run the prompt, return last-position logits.
 
     (Cache materialization for subsequent decode reuses forward compute in
-    serve.engine; the dry-run prefill cell lowers this function.)"""
+    serve.engine.)"""
     x, positions = _embed_inputs(params, cfg, batch)
     x, _ = _run_segments(params, cfg, x, positions, remat=False)
     x = C.rms_norm(x[:, -1:], params["final_norm"], cfg.norm_eps)
@@ -371,9 +361,7 @@ def forward_decode(params, cfg: ArchConfig, tokens, caches, position, *,
         seg_cache = caches[seg_idx]
 
         def body(x, inputs, pattern=pattern):
-            from repro.models.shard_hints import constrain_layer_params
             layer_params, layer_cache = inputs
-            layer_params = constrain_layer_params(layer_params, cfg)
             new_cache = {}
             for pi, (mixer, ffn) in enumerate(pattern):
                 x, _, nc = _apply_layer(

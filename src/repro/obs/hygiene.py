@@ -1,8 +1,8 @@
 """Trace-file hygiene: validate ``repro.obs`` JSONL traces.
 
-The CI perf-trajectory lane records serve/solve traces and runs this
-validator over them (``tune.hygiene``'s twin for the observability layer),
-so event-schema drift is a red build, not silent rot.  Checks:
+``tests/test_obs.py`` and ``examples/quickstart.py`` run this validator
+over the traces they record (``tune.hygiene``'s twin for the observability
+layer), so event-schema drift fails a test, not silently.  Checks:
 
 * **JSONL integrity** — every line parses as one JSON object;
 * **event schema** — required fields (``name``/``cat``/``ph``/``ts``/

@@ -3,9 +3,7 @@
 Model parameters live in the tile-heterogeneous layouts (bf16/fp32 split
 buffers); the optimizer keeps fp32 master weights + moments and re-quantizes
 into the storage layout after each update — the training-side counterpart of
-the paper's storage-precision discipline.  Under the production mesh the
-master/moment trees are additionally sharded over the "data" axis (ZeRO-1;
-see launch/sharding.zero1_spec).
+the paper's storage-precision discipline.
 """
 from __future__ import annotations
 

@@ -1,10 +1,10 @@
 """Training step: microbatched grad accumulation + AdamW + metrics.
 
 ``make_train_step(cfg, ocfg, microbatches)`` builds the pure function the
-trainer jits (and the dry-run lowers on the production mesh).  Microbatches
-split the per-step batch along batch dim and accumulate gradients in a bf16
-accumulator with error feedback (optim.grad_compress) — sequential scan, so
-peak activation memory is one microbatch.
+trainer jits.  Microbatches split the per-step batch along batch dim and
+accumulate gradients in a bf16 accumulator with error feedback
+(optim.grad_compress) — sequential scan, so peak activation memory is one
+microbatch.
 """
 from __future__ import annotations
 
@@ -65,17 +65,11 @@ def make_train_step(cfg: ArchConfig, ocfg: adamw.AdamWConfig,
                         lambda a, g: a + g.astype(a.dtype), acc, grads)
                 return (acc, err, loss_sum + loss), None
 
-            from repro.models.shard_hints import constrain_layer_params
             acc0 = jax.tree.map(
                 lambda p: jnp.zeros(
                     p.shape, jnp.bfloat16 if compress_accum else jnp.float32),
                 params)
-            # ZeRO-2: accumulator sharded over "data" on top of the param
-            # sharding — per-microbatch gradient reductions lower to
-            # reduce-scatters instead of all-reduces (EXPERIMENTS §Perf B3)
-            acc0 = constrain_layer_params(acc0, cfg, zero=True)
             err0 = GC.ef_init(params) if compress_accum else acc0
-            err0 = constrain_layer_params(err0, cfg, zero=True)
             (acc, _, loss_sum), _ = jax.lax.scan(
                 micro_step, (acc0, err0, jnp.zeros((), jnp.float32)),
                 micro, length=microbatches)

@@ -12,11 +12,10 @@ import time
 from typing import Callable, Optional
 
 import jax
-import numpy as np
 
 from repro.checkpoint import ckpt
 from repro.configs.base import ArchConfig
-from repro.data.pipeline import Prefetcher, make_batch
+from repro.data.pipeline import Prefetcher
 from repro.models import transformer as T
 from repro.optim import adamw
 from repro.runtime.fault import Heartbeat, RestartSignal, Watchdog

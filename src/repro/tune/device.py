@@ -24,7 +24,6 @@ can be built *for* a target architecture on any host (cache-only CI mode).
 from __future__ import annotations
 
 import dataclasses
-import os
 from typing import Mapping
 
 import jax

@@ -291,8 +291,8 @@ def summa_problem_from_maps(pa, pb, pc, tile: int, P: int, Q: int,
                             fset=None, *, alpha: float = 1.0,
                             beta: float = 0.0,
                             pad_free: bool = True) -> GemmProblem:
-    """Distributed plan-key problem from raw class maps (benchmarks lower
-    SUMMA from maps without materializing operands).
+    """Distributed plan-key problem from raw class maps (no operands
+    materialized).
 
     The key carries the mesh shape (in the op tag), the *per-shard* M/N
     extents (tile counts × tile), the full K, and the format-set tag, so a

@@ -210,6 +210,18 @@ def test_split_paths_within_bound(size, ratio, path, which, seed):
     _check_path(path, size, ratio, _low8_share(fset, ratio), seed, fset)
 
 
+@pytest.mark.parametrize("fnames, path, ratio", [
+    (("fp16", "fp32"), "tile", 0.0),
+    (("fp16", "split2_fp16"), "split", 1.0),
+    (("fp16", "split3_e5m2"), "split", 1.0),
+])
+def test_fp16_family_within_registry_bound(fnames, path, ratio):
+    """Uniform maps of the fp16 family — plain fp16 (all-LOW) and the split
+    compounds that recover fp32 grade from it (all-HIGH) — meet their
+    registry-derived bound."""
+    _check_path(path, 64, ratio, 0.0, 0, format_set(*fnames))
+
+
 def test_split_bound_is_fp32_grade():
     """The split2 bound itself certifies ~fp32 accuracy: orders of
     magnitude below the plain-fp16 class bound at the same K."""

@@ -3,7 +3,6 @@ import os
 import subprocess
 import sys
 import textwrap
-import time
 
 import jax
 import jax.numpy as jnp

@@ -1,6 +1,5 @@
 """Per-architecture smoke tests: reduced config of the same family, one
-forward/train step on CPU, asserting output shapes + no NaNs (the FULL
-configs are exercised via the dry-run only)."""
+forward/train step on CPU, asserting output shapes + no NaNs."""
 import jax
 import jax.numpy as jnp
 import numpy as np

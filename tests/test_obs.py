@@ -394,50 +394,6 @@ def test_engine_stats_shape_parity():
 
 
 # ---------------------------------------------------------------------------
-# bench provenance stamp + trajectory analytics
-# ---------------------------------------------------------------------------
-
-def test_write_bench_stamps_provenance(tmp_path):
-    from benchmarks.bench_io import read_bench, write_bench
-    p = str(tmp_path / "BENCH_x.json")
-    payload = write_bench(p, "gemm", [("row_a", 10.0, "ok")],
-                          meta={"smoke": True})
-    for key in ("git_sha", "timestamp_utc", "device_kind", "formats_hash"):
-        assert payload["meta"].get(key), key
-    assert payload["meta"]["smoke"] is True
-    assert read_bench(p)["meta"] == payload["meta"]
-    # explicit meta keys win over the stamp
-    payload = write_bench(p, "gemm", [], meta={"git_sha": "pinned"})
-    assert payload["meta"]["git_sha"] == "pinned"
-
-
-def _write_generation(d, sha, us):
-    os.makedirs(d, exist_ok=True)
-    payload = {"schema": 1, "suite": "gemm", "errors": [],
-               "meta": {"git_sha": sha, "timestamp_utc": f"2026-01-0{us}"},
-               "rows": [{"name": "row_a", "us_per_call": float(us),
-                         "derived": "ok"}]}
-    with open(os.path.join(d, "BENCH_gemm.json"), "w") as f:
-        json.dump(payload, f)
-
-
-def test_trajectory_joins_two_generations(tmp_path, capsys):
-    from benchmarks import trajectory
-    a, b, out = (str(tmp_path / n) for n in ("gen_a", "gen_b", "out"))
-    _write_generation(a, "a" * 40, 1)
-    _write_generation(b, "b" * 40, 2)
-    rc = trajectory.main(["--dir", a, "--dir", b, "--out-dir", out,
-                          "--smoke"])
-    assert rc == 0
-    md = open(os.path.join(out, "TRAJECTORY.md")).read()
-    assert "row_a" in md and "+100%" in md
-    svg = open(os.path.join(out, "TRAJECTORY.svg")).read()
-    assert svg.startswith("<svg") and "polyline" in svg
-    # one generation cannot form a trajectory: smoke gate fails
-    assert trajectory.main(["--dir", a, "--out-dir", out, "--smoke"]) == 1
-
-
-# ---------------------------------------------------------------------------
 # SolveReport: per-sweep wall-time + promotion records
 # ---------------------------------------------------------------------------
 

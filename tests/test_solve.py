@@ -3,7 +3,7 @@ ladder prefetch (zero mid-solve retunes), and distributed parity.
 
 The distributed tests reuse the conftest 4-host-device policy
 (``host_grid_devices`` fixture).  Sizes are kept small — the 512×512
-acceptance run lives in ``launch/solve.py`` / the solver benchmark.
+acceptance run lives in ``launch/solve.py``.
 """
 import dataclasses
 
@@ -13,7 +13,6 @@ import pytest
 
 from repro.core import MPMatrix, accuracy as ACC
 from repro.core.formats import DEFAULT_FORMATS, format_set
-from repro.core.precision import make_map
 from repro.solve import (SolveConfig, diag_dominant, graded_spd,
                          rhs_for_solution, solve)
 from repro.solve import lu as LU
@@ -231,6 +230,16 @@ def test_solve_q_start_keeps_quiet_tiles_low8():
     q_frac = float((rep.final_map == fset.low8).mean())
     assert q_frac > 0.05
     assert rep.storage_bytes < rep.uniform_high_bytes
+
+
+def test_solve_d_start_converges():
+    """20D:80S start: a fifth of the tiles HIGH from the outset still
+    converges on the ill-conditioned operator with no mid-solve retune."""
+    a = graded_spd(128, cond=1e4, rho=0.8, seed=0)
+    xt, b = rhs_for_solution(a, seed=1)
+    rep = solve(a, b, SolveConfig(tile=16, ratio_high=0.2, max_sweeps=40))
+    _check_converged(rep, xt)
+    assert rep.ratio_history[0] == "20D:80S"
 
 
 def test_solve_cg_spd():

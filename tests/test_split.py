@@ -7,7 +7,6 @@ Pallas-kernel parity, compound-format registry semantics, the ``split``
 dispatch path's cost-model rules, and the solver's compute-higher
 escalation rung.
 """
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
